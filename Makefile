@@ -50,5 +50,5 @@ chaos-test:      ## fault suite under injection (the chaos CI cell)
 serve-bench:     ## serving throughput benchmark (appends to BENCH_results.json)
 	python -m benchmarks.serve_bench
 
-serve-bench-sharded:  ## batch-1024 multi-device fan-out rows (child per device count)
+serve-bench-sharded:  ## batch-1024 multi-device fan-out rows (one process, all devices)
 	python -m benchmarks.serve_bench --sharded --quick

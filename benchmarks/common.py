@@ -1,5 +1,6 @@
 """Shared benchmark utilities: timing (paper methodology: best of N),
-structural metrics for Pallas rungs on this CPU-only host, table printing."""
+structural metrics for Pallas rungs, the per-chip peaks table, table
+printing."""
 from __future__ import annotations
 
 import json
@@ -13,9 +14,23 @@ ART = os.path.join(os.path.dirname(__file__), "artifacts", "bench")
 REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 RESULTS_PATH = os.path.join(REPO_ROOT, "BENCH_results.json")
 
-HBM_BW = 819e9
-PEAK_FLOPS = 197e12
-VPU_FLOPS = 197e12 / 8  # rough VPU (non-MXU elementwise) ceiling
+# Published per-chip peaks keyed by `jax.Device.device_kind`.  Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s int8,
+# 16 GB HBM at 819 GB/s per chip).
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes_per_s": 819e9},
+}
+TARGET_KIND = "TPU v5 lite"     # the chip the structural estimates model
+
+
+def peaks(device_kind: str) -> dict:
+    """The published peaks of one chip of `device_kind`; a device that is
+    not in the table is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]
 
 
 def best_of(fn, *args, n: int = 3, warmup: int = 1):
@@ -194,7 +209,9 @@ def fused_vs_unfused(batch, op_fn, n: int = 3):
 def kernel_structure(vc, img_shape, *, halo: int, widen: bool, extra_bytes_per_step: int = 0):
     """Structural metrics of a band kernel at a given block width (the
     TPU-side evidence for the paper's claim: wider blocks => fewer grid
-    steps / DMA issues, larger VMEM working set)."""
+    steps / DMA issues, larger VMEM working set).  `est_hbm_s` is the
+    HBM-bound time on one TARGET_KIND chip, from its published
+    bandwidth: an estimate, not a measurement."""
     H, W = img_shape[:2]
     rows = vc.rows(jnp.uint8)
     wp = W + 2 * halo
@@ -210,7 +227,7 @@ def kernel_structure(vc, img_shape, *, halo: int, widen: bool, extra_bytes_per_s
         "vmem_bytes": vmem,
         "vmem_ok": vmem <= vc.vmem_budget,
         "dma_per_step_bytes": in_bytes,
-        "est_hbm_s": hbm / HBM_BW,
+        "est_hbm_s": hbm / peaks(TARGET_KIND)["hbm_bytes_per_s"],
     }
 
 

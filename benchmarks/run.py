@@ -25,6 +25,9 @@ def main():
                              "classify", "serve", "roofline"])
     args = ap.parse_args()
 
+    from repro.core import jaxcache
+    jaxcache.enable()
+
     from benchmarks import (bow_svm_bench, classify_bench, erode_bench,
                             filter2d_bench, lmul_bench, pipeline_bench,
                             serve_bench)

@@ -8,12 +8,14 @@ the price of the robustness layer (admission checks, bucket grouping,
 per-request Response assembly) when no fault fires.
 
 `--sharded` adds the multi-device fan-out rows: batch-1024 `CvEngine`
-serves through `serve.shard_dispatch.ShardDispatcher` at 1/2/4/8 host
-devices (quick: 1/8).  Each device count runs in a CHILD process because
-`--xla_force_host_platform_device_count` must be set before jax imports;
-the child prints its row as JSON and the parent records it under bench
-key "serve" with case `serve_sharded_d<N>` (devices folded into the case
-so history matching keys each device count separately).
+serves through `serve.shard_dispatch.ShardDispatcher` over meshes of 1,
+2, 4, ... of this process's devices (quick: 1 and all), all in ONE
+process — a TPU host's chips belong to the first process that touches
+them.  Rows are recorded under bench key "serve" with case
+`serve_sharded_d<N>` (devices folded into the case so history matching
+keys each device count separately).  To rehearse on the CPU, give the
+process virtual devices before it starts
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
 
 Rows land under bench key "serve" in BENCH_results.json; the perf gate's
 `--require-serve-sharded` flag asserts the batch-1024 sharded row exists
@@ -23,11 +25,7 @@ history-tracked but not gated.
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-
+import jax
 import numpy as np
 
 from repro.cv import PipelineConfig, pipeline
@@ -39,7 +37,6 @@ from .common import best_of, flush_results, print_table, record_result
 BUCKET = (32, 32)
 MAX_KP = 16
 SHARD_BATCH = 1024
-_CHILD_MARK = "SHARD_ROW_JSON "
 
 
 def _workload(n: int):
@@ -97,21 +94,16 @@ def run(quick: bool = False) -> list[dict]:
 
 
 # ---------------------------------------------------------------------------
-# sharded fan-out rows (multi-device; child-process per device count)
+# sharded fan-out rows (multi-device, one process)
 # ---------------------------------------------------------------------------
 
-def _sharded_child(quick: bool) -> None:
-    """Runs in a child whose XLA_FLAGS already forced N host devices:
-    serve one batch-1024 workload through the sharded dispatcher and
-    print the row as JSON for the parent to record."""
-    import jax
-
+def _sharded_row(n_dev: int, work, quick: bool) -> dict:
+    """Serve one batch-1024 workload through the dispatcher over the
+    first `n_dev` devices."""
     from repro.launch.mesh import make_cv_mesh
 
-    n_dev = len(jax.devices())
-    work = _workload(SHARD_BATCH)
     eng = CvEngine(buckets=(BUCKET,), max_batch=SHARD_BATCH,
-                   max_kp=MAX_KP, mesh=make_cv_mesh())
+                   max_kp=MAX_KP, mesh=make_cv_mesh(n_dev))
     eng.extract(work[:64])                  # compile pass (shapes warm)
     serve_s = best_of(lambda _x=None: eng.extract(work), None,
                       n=1 if quick else 2)
@@ -119,7 +111,7 @@ def _sharded_child(quick: bool) -> None:
     assert all(r.ok for r in res), \
         f"{sum(not r.ok for r in res)} failed requests in sharded bench"
     d = eng.dispatcher
-    row = {
+    return {
         "batch": SHARD_BATCH,
         "case": f"serve_sharded_d{n_dev}",
         "resolution": f"{BUCKET[0]}x{BUCKET[1]}",
@@ -127,37 +119,23 @@ def _sharded_child(quick: bool) -> None:
         "images_per_s": round(SHARD_BATCH / serve_s, 2),
         "serve_best_s": round(serve_s, 4),
         "plan": res[0].plan,
-        "collective_batches": d.stats["collective_batches"],
-        "redispatches": d.stats["redispatches"],
-        "quarantined": len(d.health.quarantined()),
+        "collective_batches": d.stats["collective_batches"] if d else 0,
+        "redispatches": d.stats["redispatches"] if d else 0,
+        "quarantined": len(d.health.quarantined()) if d else 0,
     }
-    print(_CHILD_MARK + json.dumps(row))
 
 
 def run_sharded(quick: bool = False) -> list[dict]:
-    """batch-1024 serve at 1/2/4/8 host devices (quick: 1/8), one child
-    process per count (the device-count flag must precede jax import)."""
-    counts = (1, 8) if quick else (1, 2, 4, 8)
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    """batch-1024 serve over 1, 2, 4, ... of this process's devices
+    (quick: 1 and all of them)."""
+    n_all = len(jax.devices())
+    counts = {1, n_all}
+    if not quick:
+        counts |= {1 << k for k in range(n_all.bit_length())}
+    work = _workload(SHARD_BATCH)
     rows = []
-    for n in counts:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in (os.path.join(root, "src"),
-                        env.get("PYTHONPATH")) if p)
-        cmd = [sys.executable, "-m", "benchmarks.serve_bench",
-               "--sharded-child"] + (["--quick"] if quick else [])
-        proc = subprocess.run(cmd, cwd=root, env=env,
-                              capture_output=True, text=True)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"sharded bench child (devices={n}) failed:\n"
-                f"{proc.stdout[-2000:]}\n{proc.stderr[-4000:]}")
-        line = [ln for ln in proc.stdout.splitlines()
-                if ln.startswith(_CHILD_MARK)]
-        assert line, f"child (devices={n}) printed no row:\n{proc.stdout}"
-        row = json.loads(line[-1][len(_CHILD_MARK):])
+    for n in sorted(c for c in counts if c <= n_all):
+        row = _sharded_row(n, work, quick)
         rows.append(row)
         record_result("serve", row)
     headers = ["devices", "batch", "images/s", "serve_s", "plan",
@@ -177,13 +155,8 @@ if __name__ == "__main__":
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--sharded", action="store_true",
                     help="run the multi-device batch-1024 fan-out rows "
-                         "(one child process per device count)")
-    ap.add_argument("--sharded-child", action="store_true",
-                    help=argparse.SUPPRESS)    # internal: child entry
+                         "over this process's devices")
     args = ap.parse_args()
-    if args.sharded_child:
-        _sharded_child(quick=args.quick)
-        sys.exit(0)
     if args.sharded:
         run_sharded(quick=args.quick)
     else:

@@ -57,7 +57,10 @@ def v_expand_f32(a: Array) -> Array:
 
     On RVV this is where an m4 block becomes m8 (the paper's ceiling); on
     TPU it is a 4x VMEM-footprint change of the tile (int8 packs 32
-    sublanes/VREG, f32 packs 8)."""
+    sublanes/VREG, f32 packs 8).  Integer carriers widen through i32:
+    Mosaic has no direct u8 -> f32 cast."""
+    if jnp.issubdtype(a.dtype, jnp.integer):
+        a = a.astype(jnp.int32)
     return a.astype(jnp.float32)
 
 
@@ -71,7 +74,8 @@ def v_pack_u8(a: Array) -> Array:
     vnclipu on RVV): f32/i32 -> u8."""
     if jnp.issubdtype(a.dtype, jnp.floating):
         a = jnp.round(a)
-    return jnp.clip(a, 0, 255).astype(jnp.uint8)
+    # through i32: Mosaic has no direct f32 -> u8 cast
+    return jnp.clip(a, 0, 255).astype(jnp.int32).astype(jnp.uint8)
 
 
 def v_select(mask: Array, a: Array, b: Array) -> Array:

@@ -23,7 +23,9 @@ import jax.numpy as jnp
 _SUBLANES = {1: 32, 2: 16, 4: 8}
 
 LANE = 128          # TPU vector lanes
-VMEM_BYTES = 16 * 2**20   # v5e VMEM per core (approx usable)
+# the planner's per-kernel VMEM budget; a v5e core has 128 MiB of VMEM,
+# and Mosaic refuses only what exceeds that
+VMEM_BYTES = 16 * 2**20
 
 
 def sublanes(dtype) -> int:
@@ -37,7 +39,7 @@ class VectorConfig:
     lane: int = LANE
     base_rows: int = 8             # fp32 sublanes; dtype packing scales this
     vmem_budget: int = VMEM_BYTES
-    interpret: bool | None = None  # None = auto (True unless on real TPU)
+    interpret: bool | None = None  # None = auto: interpret on CPU only
 
     def rows(self, dtype=jnp.float32) -> int:
         """Tile rows for `dtype` at this lmul (sublane packing x lmul)."""
@@ -51,9 +53,18 @@ class VectorConfig:
 
     @property
     def run_interpret(self) -> bool:
+        """Compile for the TPU, or interpret on the CPU backend.  Any other
+        backend is an error: the kernels are written for Mosaic, and an
+        interpreted run there would time the interpreter, not the device."""
         if self.interpret is not None:
             return self.interpret
-        return jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        if backend == "tpu":
+            return False
+        if backend == "cpu":
+            return True
+        raise RuntimeError(f"Pallas TPU kernels cannot run on backend "
+                           f"{backend!r}: use a TPU, or the CPU to interpret")
 
     def with_lmul(self, lmul: int) -> "VectorConfig":
         return replace(self, lmul=lmul)

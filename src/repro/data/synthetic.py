@@ -8,6 +8,8 @@ signal.
 """
 from __future__ import annotations
 
+import zlib
+
 import numpy as np
 import jax.numpy as jnp
 
@@ -44,32 +46,41 @@ class TokenStream:
 class ImageStream:
     """Synthetic CIFAR-like classification set: 10 generative classes with
     distinct spatial structure (bars, blobs, checker, gradient x frequency),
-    32x32x3 u8 — same compute character as the paper's Cifar-10 testbed."""
+    u8 C3 — same compute character as the paper's Cifar-10 testbed at the
+    default 32x32.  `res` is a side length or an (H, W) pair; the structure
+    scales with the frame (at 32x32 the frames are the historical ones)."""
 
-    def __init__(self, *, n_classes: int = 10, res: int = 32, seed: int = 0):
+    def __init__(self, *, n_classes: int = 10, res=32, seed: int = 0):
         self.n_classes = n_classes
         self.res = res
         self.seed = seed
 
     def batch(self, n: int, *, split: str = "train"):
-        rng = np.random.default_rng((self.seed, hash(split) % 2**31))
+        # crc32, not hash(): str hashes are salted per process, and a
+        # seeded set must be the same set in every run
+        rng = np.random.default_rng((self.seed, zlib.crc32(split.encode())))
+        h, w = (self.res, self.res) if np.isscalar(self.res) else self.res
+        size = min(h, w)                # length unit of the class structure
+        scale = size / 32
         y = rng.integers(0, self.n_classes, n)
-        xs = np.zeros((n, self.res, self.res, 3), np.uint8)
-        i_idx, j_idx = np.meshgrid(np.arange(self.res), np.arange(self.res), indexing="ij")
+        xs = np.zeros((n, h, w, 3), np.uint8)
+        i_idx, j_idx = np.meshgrid(np.arange(h), np.arange(w), indexing="ij")
         for i in range(n):
             c = y[i]
             phase = rng.random() * 2 * np.pi
             freq = 1 + (c % 5)
             angle = (c // 5) * np.pi / 4 + rng.normal(0, 0.1)
-            wave = np.sin(freq * 2 * np.pi / self.res *
+            wave = np.sin(freq * 2 * np.pi / size *
                           (np.cos(angle) * i_idx + np.sin(angle) * j_idx) + phase)
-            blob_x, blob_y = rng.integers(8, 24, 2)
-            blob = np.exp(-(((i_idx - blob_x) ** 2 + (j_idx - blob_y) ** 2) / (2 + 3 * (c % 3)) ** 2))
+            blob_i, blob_j = rng.integers(8, 24, 2)
+            blob_i, blob_j = blob_i * h / 32, blob_j * w / 32
+            radius = (2 + 3 * (c % 3)) * scale
+            blob = np.exp(-(((i_idx - blob_i) ** 2 + (j_idx - blob_j) ** 2) / radius ** 2))
             img = 0.6 * wave + 0.8 * blob * ((c % 2) * 2 - 1)
             img = img + rng.normal(0, 0.15, img.shape)
             for ch in range(3):
-                scale = 0.5 + 0.5 * np.sin(c + ch)
-                xs[i, :, :, ch] = np.clip((img * scale * 0.5 + 0.5) * 255, 0, 255)
+                scale_ch = 0.5 + 0.5 * np.sin(c + ch)
+                xs[i, :, :, ch] = np.clip((img * scale_ch * 0.5 + 0.5) * 255, 0, 255)
         return jnp.asarray(xs), jnp.asarray(y, jnp.int32)
 
     def image(self, resolution: tuple[int, int], *, channels: int = 1, seed: int = 0):

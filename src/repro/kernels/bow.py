@@ -40,6 +40,10 @@ from repro.core.vector import VectorConfig
 
 Array = jax.Array
 
+# f32 contractions at f32 precision on the MXU (the TPU default for f32
+# is one bf16 pass), matching the staged oracles, which ask for the same
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _pad_codebook(centroids: Array, bk: int):
     """Pad (K, D) centroids to a bk multiple; pad rows masked with +inf
@@ -49,10 +53,25 @@ def _pad_codebook(centroids: Array, bk: int):
     c = jnp.pad(centroids.astype(jnp.float32), ((0, k_pad), (0, 0)))
     c2 = jnp.sum(c * c, axis=1)
     c2 = jnp.where(jnp.arange(c.shape[0]) < K, c2, jnp.inf)
-    return c, c2
+    return c, c2[None, :]
 
 
-def _bow_kernel(d_ref, c_ref, c2_ref, idx_ref, val_ref, minv, mini, *, bn, bk):
+def _running_argmin(d, c_ref, c2_ref, minv, mini, kb, bk):
+    """Fold one centroid block into the running (min, argmin) scratch:
+    s = -2 d.c + |c|^2 (|d|^2 is constant per row: argmin-invariant).
+    Every per-row vector is a (bn, 1) column, a layout Mosaic tiles."""
+    s = -2.0 * jax.lax.dot_general(d, c_ref[...], (((1,), (1,)), ((), ())),
+                                   precision=_HIGHEST,
+                                   preferred_element_type=jnp.float32)
+    s = s + c2_ref[...]                                # (1, bk) row
+    bmin = jnp.min(s, axis=1, keepdims=True)
+    barg = jnp.argmin(s, axis=1, keepdims=True).astype(jnp.int32) + kb * bk
+    better = bmin < minv[...]
+    mini[...] = jnp.where(better, barg, mini[...])
+    minv[...] = jnp.where(better, bmin, minv[...])
+
+
+def _bow_kernel(d_ref, c_ref, c2_ref, idx_ref, val_ref, minv, mini, *, bk):
     kb = pl.program_id(1)
     nk = pl.num_programs(1)
 
@@ -62,20 +81,10 @@ def _bow_kernel(d_ref, c_ref, c2_ref, idx_ref, val_ref, minv, mini, *, bn, bk):
     # exceeds any finite sentinel (the empty-descriptor-block edge).
     @pl.when(kb == 0)
     def _init():
-        minv[...] = jnp.full((bn,), jnp.inf, jnp.float32)
-        mini[...] = jnp.zeros((bn,), jnp.int32)
+        minv[...] = jnp.full(minv.shape, jnp.inf, jnp.float32)
+        mini[...] = jnp.zeros(mini.shape, jnp.int32)
 
-    d = d_ref[...]                                     # (bn, D) f32
-    c = c_ref[...]                                     # (bk, D) f32
-    # -2 d.c + |c|^2  (|d|^2 is constant per row: argmin-invariant)
-    s = -2.0 * jax.lax.dot_general(d, c, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-    s = s + c2_ref[...][None, :]
-    bmin = jnp.min(s, axis=1)
-    barg = jnp.argmin(s, axis=1).astype(jnp.int32) + kb * bk
-    better = bmin < minv[...]
-    mini[...] = jnp.where(better, barg, mini[...])
-    minv[...] = jnp.where(better, bmin, minv[...])
+    _running_argmin(d_ref[...], c_ref, c2_ref, minv, mini, kb, bk)
 
     @pl.when(kb == nk - 1)
     def _done():
@@ -107,32 +116,32 @@ def bow_assign(desc: Array, centroids: Array, *, vc: VectorConfig = VectorConfig
     c, c2 = _pad_codebook(centroids, bk)
 
     idx, val = pl.pallas_call(
-        functools.partial(_bow_kernel, bn=bn, bk=bk),
+        functools.partial(_bow_kernel, bk=bk),
         grid=(d.shape[0] // bn, c.shape[0] // bk),
         in_specs=[
             pl.BlockSpec((bn, D), lambda n, k: (n, 0)),
             pl.BlockSpec((bk, D), lambda n, k: (k, 0)),
-            pl.BlockSpec((bk,), lambda n, k: (k,)),
+            pl.BlockSpec((1, bk), lambda n, k: (0, k)),
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda n, k: (n,)),
-            pl.BlockSpec((bn,), lambda n, k: (n,)),
+            pl.BlockSpec((bn, 1), lambda n, k: (n, 0)),
+            pl.BlockSpec((bn, 1), lambda n, k: (n, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((d.shape[0],), jnp.int32),
-            jax.ShapeDtypeStruct((d.shape[0],), jnp.float32),
+            jax.ShapeDtypeStruct((d.shape[0], 1), jnp.int32),
+            jax.ShapeDtypeStruct((d.shape[0], 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.int32),
+            pltpu.VMEM((bn, 1), jnp.float32),
+            pltpu.VMEM((bn, 1), jnp.int32),
         ],
         interpret=vc.run_interpret,
     )(d, c, c2)
-    d2 = val[:N] + jnp.sum(desc.astype(jnp.float32) ** 2, axis=1)
-    return idx[:N], d2
+    d2 = val[:N, 0] + jnp.sum(desc.astype(jnp.float32) ** 2, axis=1)
+    return idx[:N, 0], d2
 
 
-def _hist_kernel(d_ref, w_ref, c_ref, c2_ref, h_ref, minv, mini, *, bn, bk, kp):
+def _hist_kernel(d_ref, w_ref, c_ref, c2_ref, h_ref, minv, mini, *, bk, kp):
     nb = pl.program_id(1)
     kb = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -145,29 +154,21 @@ def _hist_kernel(d_ref, w_ref, c_ref, c2_ref, h_ref, minv, mini, *, bn, bk, kp):
 
     @pl.when(kb == 0)
     def _init():
-        minv[...] = jnp.full((bn,), jnp.inf, jnp.float32)
-        mini[...] = jnp.zeros((bn,), jnp.int32)
+        minv[...] = jnp.full(minv.shape, jnp.inf, jnp.float32)
+        mini[...] = jnp.zeros(mini.shape, jnp.int32)
 
-    d = d_ref[0]                                       # (bn, D) f32
-    c = c_ref[...]                                     # (bk, D) f32
-    s = -2.0 * jax.lax.dot_general(d, c, (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-    s = s + c2_ref[...][None, :]
-    bmin = jnp.min(s, axis=1)
-    barg = jnp.argmin(s, axis=1).astype(jnp.int32) + kb * bk
-    better = bmin < minv[...]
-    mini[...] = jnp.where(better, barg, mini[...])
-    minv[...] = jnp.where(better, bmin, minv[...])
+    _running_argmin(d_ref[0], c_ref, c2_ref, minv, mini, kb, bk)
 
     @pl.when(kb == nk - 1)
     def _accumulate():
         # segment-sum of the block's valid weights by winning centroid:
         # one-hot(assignment) scaled by weight, reduced over rows — the
         # assignment indices stay in VMEM scratch, never reaching HBM
-        w = w_ref[0]                                   # (bn,) f32
+        w = w_ref[0]                                   # (bn, 1) f32
+        bn = w.shape[0]
         oh = (jax.lax.broadcasted_iota(jnp.int32, (bn, kp), 1)
-              == mini[...][:, None]).astype(jnp.float32)
-        h_ref[...] += jnp.sum(oh * w[:, None], axis=0)[None, :]
+              == mini[...]).astype(jnp.float32)
+        h_ref[...] += jnp.sum(oh * w, axis=0, keepdims=True)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("vc", "normalize"))
@@ -201,35 +202,37 @@ def bow_quantize_hist(descs: Array, valids: Array, centroids: Array, *,
     c, c2 = _pad_codebook(centroids, bk)
     kp = c.shape[0]
 
+    # weights ride as (bn, 1) columns and each image's histogram as a
+    # (1, kp) row of a (B, 1, kp) array: both minor dims stay whole or
+    # (8, 128)-aligned for any B and bn
     h = pl.pallas_call(
-        functools.partial(_hist_kernel, bn=bn, bk=bk, kp=kp),
+        functools.partial(_hist_kernel, bk=bk, kp=kp),
         grid=(B, d.shape[1] // bn, kp // bk),
         in_specs=[
             pl.BlockSpec((1, bn, D), lambda b, n, k: (b, n, 0)),
-            pl.BlockSpec((1, bn), lambda b, n, k: (b, n)),
+            pl.BlockSpec((1, bn, 1), lambda b, n, k: (b, n, 0)),
             pl.BlockSpec((bk, D), lambda b, n, k: (k, 0)),
-            pl.BlockSpec((bk,), lambda b, n, k: (k,)),
+            pl.BlockSpec((1, bk), lambda b, n, k: (0, k)),
         ],
-        out_specs=pl.BlockSpec((1, kp), lambda b, n, k: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, kp), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, kp), lambda b, n, k: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, kp), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.int32),
+            pltpu.VMEM((bn, 1), jnp.float32),
+            pltpu.VMEM((bn, 1), jnp.int32),
         ],
         interpret=vc.run_interpret,
-    )(d, w, c, c2)
-    h = h[:, :K]
+    )(d, w[..., None], c, c2)
+    h = h[:, 0, :K]
     if normalize:
         h = h / jnp.maximum(jnp.sum(h, axis=1, keepdims=True), 1e-6)
     return h
 
 
 def _score_kernel(h_ref, w_ref, b_ref, s_ref):
-    h = h_ref[...]                                     # (bb, Kp) f32
-    w = w_ref[...]                                     # (Cp, Kp) f32
-    s = jax.lax.dot_general(h, w, (((1,), (1,)), ((), ())),
+    s = jax.lax.dot_general(h_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+                            precision=_HIGHEST,
                             preferred_element_type=jnp.float32)
-    s_ref[...] = s + b_ref[...][None, :]
+    s_ref[...] = s + b_ref[...]                        # (1, C) row
 
 
 @functools.partial(jax.jit, static_argnames=("vc",))
@@ -238,29 +241,24 @@ def linear_score(hists: Array, w: Array, b: Array, *,
     """Fused one-vs-rest linear scoring: hists (B, K), w (C, K), b (C,)
     -> decision scores (B, C) f32 in one launch, weights VMEM-resident.
 
-    Zero-padded K/C margins: pad classes score b_pad = -inf so a
-    downstream argmax can never pick them (they are sliced off here
-    anyway); pad histogram words multiply zero weights.
+    Every operand is one whole-array block (no padding): the contraction
+    then has exactly the staged oracle's shapes, which is what keeps the
+    scores bit-identical to `ref.svm_decision_ref`.  The batch of
+    histograms is VMEM-resident too (B * K * 4 bytes; 64 KiB per 64
+    images at k=250).
     """
     B, K = hists.shape
     C = w.shape[0]
-    bb = vc.rows(jnp.float32) * 4
-    bp, kp, cp = (-B) % bb, (-K) % 128, (-C) % 128
-    h = jnp.pad(hists.astype(jnp.float32), ((0, bp), (0, kp)))
-    wp = jnp.pad(w.astype(jnp.float32), ((0, cp), (0, kp)))
-    bv = jnp.pad(b.astype(jnp.float32), (0, cp),
-                 constant_values=-jnp.inf)
-    s = pl.pallas_call(
+    return pl.pallas_call(
         _score_kernel,
-        grid=(h.shape[0] // bb,),
+        grid=(1,),
         in_specs=[
-            pl.BlockSpec((bb, h.shape[1]), lambda i: (i, 0)),
-            pl.BlockSpec(wp.shape, lambda i: (0, 0)),
-            pl.BlockSpec(bv.shape, lambda i: (0,)),
+            pl.BlockSpec((B, K), lambda i: (0, 0)),
+            pl.BlockSpec((C, K), lambda i: (0, 0)),
+            pl.BlockSpec((1, C), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((bb, bv.shape[0]), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((h.shape[0], bv.shape[0]),
-                                       jnp.float32),
+        out_specs=pl.BlockSpec((B, C), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
         interpret=vc.run_interpret,
-    )(h, wp, bv)
-    return s[:B, :C]
+    )(hists.astype(jnp.float32), w.astype(jnp.float32),
+      b.astype(jnp.float32)[None, :])

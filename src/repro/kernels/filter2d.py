@@ -4,7 +4,7 @@ single-stage chains of the fused stencil engine (see stencil.py).
 Band decomposition: a (planes, bands) grid where `rows = vc.rows(dtype)`
 (= sublane-packing x lmul — the paper's register-block knob). The row halo
 arrives in the same DMA as the band via one overlapping-window BlockSpec
-(`pl.Unblocked`); the column halo is handled by pre-padding the width and
+(`pl.Element` offsets); the column halo is handled by pre-padding the width and
 rotating lanes in-register (uintr.v_shift_cols == RVV vslide). Channels and
 batch images are grid dimensions, not Python loops, so a (B, H, W, C)
 input is one `pallas_call`.

@@ -34,6 +34,10 @@ from repro.core.vector import VectorConfig
 
 Array = jax.Array
 
+# the 0/1 selection and packing matmuls are exact only at f32 precision
+# (the TPU default for f32 is one bf16 pass, which rounds x)
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _pad128(n: int) -> int:
     return n + (-n) % 128
@@ -43,18 +47,22 @@ def _gbdt_kernel(x_ref, s_ref, thr_ref, p_ref, off_ref, e_ref, lv_ref,
                  sc_ref, li_ref):
     x = x_ref[...]                                     # (bb, Fp) f32
     xs = jax.lax.dot_general(x, s_ref[...], (((1,), (1,)), ((), ())),
+                             precision=_HIGHEST,
                              preferred_element_type=jnp.float32)
     bits = (xs > thr_ref[...][None, :]).astype(jnp.float32)
     lidx = jax.lax.dot_general(bits, p_ref[...], (((1,), (0,)), ((), ())),
+                               precision=_HIGHEST,
                                preferred_element_type=jnp.float32)
     li_ref[...] = lidx.astype(jnp.int32)               # (bb, Tp)
     g = lidx + off_ref[...][None, :]                   # global (tree, leaf)
     gexp = jax.lax.dot_general(g, e_ref[...], (((1,), (0,)), ((), ())),
+                               precision=_HIGHEST,
                                preferred_element_type=jnp.float32)
     j = jax.lax.broadcasted_iota(jnp.int32, gexp.shape, 1)
     oh = (gexp == j.astype(jnp.float32)).astype(jnp.float32)
     sc_ref[...] = jax.lax.dot_general(oh, lv_ref[...],
                                       (((1,), (0,)), ((), ())),
+                                      precision=_HIGHEST,
                                       preferred_element_type=jnp.float32)
 
 

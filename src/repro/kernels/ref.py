@@ -434,7 +434,7 @@ def bow_histogram_ref(assign: Array, K: int, *, normalize: bool = True) -> Array
 
 def svm_decision_ref(x: Array, w: Array, b: Array) -> Array:
     """Linear multi-class decision values: x (N, D), w (C, D), b (C,)."""
-    return x @ w.T + b[None, :]
+    return jnp.dot(x, w.T, precision=jax.lax.Precision.HIGHEST) + b[None, :]
 
 
 def bow_hist_ref(descs: Array, valids: Array, centroids: Array, *,
@@ -454,7 +454,8 @@ def bow_hist_ref(descs: Array, valids: Array, centroids: Array, *,
     K = centroids.shape[0]
     d = descs.astype(jnp.float32).reshape(B * N, D)
     c = centroids.astype(jnp.float32)
-    s = -2.0 * d @ c.T + jnp.sum(c * c, axis=1)[None, :]
+    s = (-2.0 * jnp.dot(d, c.T, precision=jax.lax.Precision.HIGHEST)
+         + jnp.sum(c * c, axis=1)[None, :])
     idx = jnp.argmin(s, axis=1).astype(jnp.int32).reshape(B, N)
     w = valids.astype(jnp.float32)
     h = jnp.zeros((B, K), jnp.float32)

@@ -14,7 +14,8 @@ over a batched, multi-channel image into a **single `pallas_call`**:
     and the grid is `(N, n_tiles, n_bands)` — the per-channel / per-image
     Python loops of the old wrappers become grid dimensions;
   * each grid step DMAs **one** overlapping window of input rows
-    (`pl.Unblocked` indexing) sized by the backward recurrence
+    (`pl.Element` offsets, rounded up to the sublane tile) sized by the
+    backward recurrence
     `R_in = R_out * stride + 2*halo` over the whole chain, so a band's
     bytes cross HBM->VMEM once;
   * every stage runs in-register/in-VMEM on the band, consuming its own

@@ -15,8 +15,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-from repro.core import compat
+from jax.extend import core as jex_core
 
 from .. import ref
 from . import exec_ref, exec_streaming, exec_window, ir
@@ -50,9 +49,9 @@ def count_pallas_calls(fn, *args, **kwargs) -> int:
             if eqn.primitive.name == "pallas_call":
                 n += 1
             for v in eqn.params.values():
-                if isinstance(v, compat.ClosedJaxpr):
+                if isinstance(v, jex_core.ClosedJaxpr):
                     n += walk(v.jaxpr)
-                elif isinstance(v, compat.Jaxpr):
+                elif isinstance(v, jex_core.Jaxpr):
                     n += walk(v)
         return n
     return walk(jax.make_jaxpr(fn)(*args, **kwargs).jaxpr)
@@ -73,6 +72,12 @@ def _chain_planes(planes: Array, weights: tuple, spec: tuple,
     crop rules — all comes from `plan.build_chain_geom`; this function is
     only the Plan -> executor seam."""
     stages = respec(spec, weights)
+    if not vc.run_interpret:
+        bad = sorted({s.op for s in stages} & exec_window.NO_TPU_LOWERING)
+        if bad:
+            raise NotImplementedError(
+                f"fused_chain: stage(s) {bad} have no TPU lowering "
+                "(exec_window.NO_TPU_LOWERING); run them with mode='ref'")
     geom = build_chain_geom(stages, planes.shape, planes.dtype, vc,
                             stream=stream, tile_w=tile_w)
     ex = exec_streaming if stream else exec_window

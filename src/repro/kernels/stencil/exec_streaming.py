@@ -19,8 +19,8 @@ from jax.experimental import pallas as pl
 import jax.numpy as jnp
 
 from .exec_window import (_apply_grad_pair, _apply_sobel, _materialize,
-                          apply_stage, launch, split_refs, store_bands,
-                          window_pass)
+                          apply_stage, launch, read_rows, split_refs,
+                          store_bands, window_pass)
 
 Array = jax.Array
 
@@ -34,7 +34,7 @@ def stream_pass(x_ref, ring_refs, wts_k, plan, carrier, interp, band_i,
     mult0, r0, sstages = splan
     # each live band is represented by its `mult` NEW rows at the
     # current stage's input; band 0 starts as the window's fresh tail
-    news = [x_ref[..., r0 - mult0:r0, :]]
+    news = [read_rows(x_ref, r0 - mult0, r0)]
     for k, (op, static, mode, tap, (ph, pw), _wmeta) in enumerate(plan):
         sin_lo, sin_r, ring_rows, d_rows, op_rids, d_rids, smeta = \
             sstages[k]
@@ -45,7 +45,7 @@ def stream_pass(x_ref, ring_refs, wts_k, plan, carrier, interp, band_i,
             # stage body input = carried ring rows ++ upstream new rows
             # (stage 0 slices the window: its history is DMA-resident)
             if sin_lo is not None:
-                return x_ref[..., sin_lo:sin_lo + sin_r, :]
+                return read_rows(x_ref, sin_lo, sin_lo + sin_r)
             if ring_rows == 0:
                 return src
             buf = jnp.concatenate([ring_refs[rid][...], src], axis=-2)
@@ -95,8 +95,8 @@ def streaming_kernel(x_ref, *refs, plan, carrier, interp, n_out, splan,
 
     @pl.when(band_i == 0)
     def _():
-        bands = window_pass(x_ref, ring_refs, wts_k, plan, carrier, interp,
-                            band_i, tile_j, splan=splan, prime=True)
+        bands = window_pass(x_ref, splan[1], ring_refs, wts_k, plan, carrier,
+                            interp, band_i, tile_j, splan=splan, prime=True)
         store_bands(out_refs, bands, store_slices)
 
     @pl.when(band_i != 0)

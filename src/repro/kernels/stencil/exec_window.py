@@ -21,8 +21,14 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import uintr
 
 from .ir import _N_WEIGHTS, _gather_halo
+from .plan import round_sublanes
 
 Array = jax.Array
+
+
+# stage kinds whose bodies Mosaic refuses: the gathers index a flattened
+# band (`jnp.take`), and pyr_up's input window does not lower either
+NO_TPU_LOWERING = frozenset({"warp_affine", "remap", "pyr_up"})
 
 
 def _pack(acc: Array, carrier) -> Array:
@@ -100,6 +106,64 @@ def _apply_box(band, wts, static, carrier, *, interp=False):
     return _pack(acc * jnp.float32(1.0 / (k * k)), carrier)
 
 
+# Mosaic lowers neither a strided value slice (a gather) nor a reshape that
+# splits a sublane or lane pair, so decimation and interleaving are 0/1
+# matmuls at f32 precision: exact, since every output is one product by
+# 1.0 plus zeros.
+def _pairing(shape, long_axis: int, phase: int = 0) -> Array:
+    """0/1 f32 matrix: one where the index along `long_axis` is twice the
+    other index plus `phase` (picks, or places, every other row)."""
+    long = jax.lax.broadcasted_iota(jnp.int32, shape, long_axis)
+    short = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - long_axis)
+    return (long == 2 * short + phase).astype(jnp.float32)
+
+
+def _left(m: Array, x: Array) -> Array:
+    """m @ plane for every (R, W) plane of x."""
+    planes = x.reshape((-1,) + x.shape[-2:])
+    out = jnp.stack([jnp.dot(m, planes[i], precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
+                     for i in range(planes.shape[0])])
+    return out.reshape(x.shape[:-2] + out.shape[-2:])
+
+
+def _right(x: Array, m: Array) -> Array:
+    """x @ m over consecutive column chunks of m's height, so each chunk
+    lands as whole lanes (strided chains pad widths to lane x stride)."""
+    c = m.shape[0]
+    rows = x.reshape((-1, x.shape[-1]))
+    out = jnp.concatenate(
+        [jnp.dot(rows[:, j:j + c], m, precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)
+         for j in range(0, x.shape[-1], c)], axis=-1)
+    return out.reshape(x.shape[:-1] + out.shape[-1:])
+
+
+def _even_rows(x: Array) -> Array:
+    """x[..., 0::2, :]"""
+    r = x.shape[-2]
+    return _left(_pairing((r // 2, r), 1), x)
+
+
+def _even_cols(x: Array) -> Array:
+    """x[..., 0::2]"""
+    c = min(x.shape[-1], 256)
+    return _right(x, _pairing((c, c // 2), 0))
+
+
+def _interleave_rows(ev: Array, od: Array) -> Array:
+    """Rows ev[0], od[0], ev[1], od[1], ..."""
+    shape = (2 * ev.shape[-2], ev.shape[-2])
+    return _left(_pairing(shape, 0), ev) + _left(_pairing(shape, 0, 1), od)
+
+
+def _interleave_cols(ev: Array, od: Array) -> Array:
+    """Columns ev[0], od[0], ev[1], od[1], ..."""
+    c = min(ev.shape[-1], 128)
+    return (_right(ev, _pairing((c, 2 * c), 1))
+            + _right(od, _pairing((c, 2 * c), 1, 1)))
+
+
 def _apply_pyr_down(band, wts, static, carrier, *, interp=False):
     """5-tap separable Gaussian, then decimation of even rows/cols.  The
     planner sizes the band so the valid output has exactly 2x the output
@@ -114,16 +178,18 @@ def _apply_pyr_down(band, wts, static, carrier, *, interp=False):
     acc = jnp.zeros(_out_shape(band, out_rows), jnp.float32)
     for i in range(5):
         acc = uintr.v_fma(rowacc[..., i:i + out_rows, :], k1[i], acc)
-    return _pack(acc[..., 0::2, 0::2], carrier)
+    return _pack(_even_cols(_even_rows(acc)), carrier)
 
 
 def _apply_resize2(band, wts, static, carrier, *, interp=False):
     """2x2-mean downsample: row pairs + lane-shifted column pairs, * 0.25."""
     x = _expand_once(band, interp)
-    rows = band.shape[-2]
-    r = x[..., 0:rows:2, :] + x[..., 1:rows:2, :]
+    # row pairs: each row plus its successor, even rows kept
+    r = _even_rows(uintr.v_add(x, uintr.v_shift_rows(x, -1)))
     c = uintr.v_add(r, uintr.v_shift_cols(r, -1))
-    return _pack(c[..., 0::2] * jnp.float32(0.25), carrier)
+    # scale before the (exact) selection: the selection matmul then ends
+    # the stage, so no consumer can fuse the scale into its own FMAs
+    return _pack(_even_cols(c * jnp.float32(0.25)), carrier)
 
 
 def _apply_pyr_up(band, carrier, meta, *, interp=False):
@@ -139,17 +205,13 @@ def _apply_pyr_up(band, carrier, meta, *, interp=False):
     c = x[..., 2:rows, :]
     ev = (a + 6.0 * b + c) * jnp.float32(0.125)
     od = (b + c) * jnp.float32(0.5)
-    t = jnp.stack([ev, od], axis=-2)
-    t = t.reshape(t.shape[:-3] + (2 * (rows - 2), t.shape[-1]))
-    t = t[..., p2:p2 + r_out, :]
+    t = _interleave_rows(ev, od)[..., p2:p2 + r_out, :]
     if interp:
         t = _materialize(t)     # both column phases consume every row
     left, right = uintr.v_shift_cols(t, 1), uintr.v_shift_cols(t, -1)
     evc = (left + 6.0 * t + right) * jnp.float32(0.125)
     odc = (t + right) * jnp.float32(0.5)
-    u = jnp.stack([evc, odc], axis=-1)
-    u = u.reshape(u.shape[:-3] + (u.shape[-3], 2 * u.shape[-2]))
-    return _pack(u, carrier)
+    return _pack(_interleave_cols(evc, odc), carrier)
 
 
 def _bilinear_band(x, sy, sx, oy, ox, carrier, *, interp=False):
@@ -262,6 +324,9 @@ def _apply_morph(band, wts, static, carrier, *, op, interp=False):
         return jax.lax.reduce_window(band, init, comp, window,
                                      (1,) * band.ndim, pad)
     red = uintr.v_min if op == "erode" else uintr.v_max
+    narrow = band.dtype
+    if jnp.issubdtype(narrow, jnp.integer) and jnp.dtype(narrow).itemsize < 4:
+        band = band.astype(jnp.int32)   # no packed-int min/max on the VPU
     out_rows = band.shape[-2] - 2 * r
     # separable in-register: column min/max over 2r+1 rows, then one uniform
     # lane-shift loop over the 2r+1 column offsets (j == 0 folded in).
@@ -272,7 +337,7 @@ def _apply_morph(band, wts, static, carrier, *, op, interp=False):
     for j in range(2 * r + 1):
         shifted = uintr.v_shift_cols(acc, r - j)
         out = shifted if out is None else red(out, shifted)
-    return out
+    return out.astype(narrow)
 
 
 def _apply_threshold(band, wts, static, carrier, *, interp=False):
@@ -280,9 +345,17 @@ def _apply_threshold(band, wts, static, carrier, *, interp=False):
     # compare in f32: fractional thresholds must not truncate on integer
     # carriers (thresh=127.5 on u8 is x >= 128, not x > 127)
     t = jnp.float32(thresh)
+    mask = uintr.v_expand_f32(band) > t
+    if jnp.issubdtype(carrier, jnp.integer):
+        # select on i32 and narrow: the f32 compare's mask cannot be
+        # relaid out to a packed-int select on TPU
+        info = jnp.iinfo(carrier)
+        hi = min(max(int(maxval), info.min), info.max)
+        out = uintr.v_select(mask, jnp.int32(hi), jnp.int32(0))
+        return out.astype(carrier)
     hi = jnp.asarray(maxval).astype(carrier)
     lo = jnp.asarray(0).astype(carrier)
-    return uintr.v_select(uintr.v_expand_f32(band) > t, hi, lo)
+    return uintr.v_select(mask, hi, lo)
 
 
 def _apply_affine(band, wts, static, carrier, *, interp=False):
@@ -380,13 +453,23 @@ def store_bands(out_refs, bands, store_slices):
         out_ref[...] = b[..., loc0:loc0 + store_w]
 
 
-def window_pass(x_ref, ring_refs, wts_k, plan, carrier, interp, band_i,
-                tile_j, splan=None, prime=False):
+def read_rows(x_ref, lo: int, hi: int) -> Array:
+    """Rows [lo, hi) of the DMA'd window.  Mosaic slices VMEM only on
+    sublane-tile boundaries, so load the aligned cover and slice the
+    value (the block itself is tile-rounded, so the cover is in bounds)."""
+    lo_a = lo - lo % round_sublanes(1, x_ref.dtype)
+    hi_a = round_sublanes(hi, x_ref.dtype)
+    return x_ref[:, lo_a:hi_a, :][:, lo - lo_a:hi - lo_a, :]
+
+
+def window_pass(x_ref, r_window, ring_refs, wts_k, plan, carrier, interp,
+                band_i, tile_j, splan=None, prime=False):
     """Run the whole chain over the DMA'd window; returns the band list.
     ``prime=True`` (streaming step 0) additionally fills every scratch ring
     with the tail rows of each band's stream — exactly what step 1 must
     read."""
-    bands = [x_ref[...]]             # (P, R_window, WP) carrier dtype
+    # (P, R_window, WP) carrier dtype
+    bands = [read_rows(x_ref, 0, r_window)]
     for k, (op, static, mode, tap, (ph, pw), meta) in enumerate(plan):
         wts = wts_k[k]
         if prime:
@@ -424,12 +507,13 @@ def window_pass(x_ref, ring_refs, wts_k, plan, carrier, interp, band_i,
     return bands
 
 
-def window_kernel(x_ref, *refs, plan, carrier, interp, n_out, store_slices):
+def window_kernel(x_ref, *refs, plan, carrier, interp, n_out, store_slices,
+                  r_window):
     """The overlapping-window plan: every grid step recomputes the full
     chain over its own window (no carried state)."""
     wts_k, out_refs, _ = split_refs(refs, plan, n_out, 0)
     band_i, tile_j = pl.program_id(2), pl.program_id(1)
-    bands = window_pass(x_ref, (), wts_k, plan, carrier, interp,
+    bands = window_pass(x_ref, r_window, (), wts_k, plan, carrier, interp,
                         band_i, tile_j)
     store_bands(out_refs, bands, store_slices)
 
@@ -454,21 +538,26 @@ def launch(planes: Array, stages, geom, vc, kernel) -> tuple:
                                         lambda n, t, i, nd=w.ndim: (0,) * nd))
             w_args.append(w)
 
+    # outputs carry the band index as their own axis, so a band of fewer
+    # rows than the sublane tile (a strided tap's) is still a whole-array
+    # minor dim; merging the two axes afterwards is a reshape
     out_specs, out_shapes, crops = [], [], []
     for bdt, rows_k, store_w, loc0, h_k, w_k, crop_off in g.outs:
-        out_specs.append(pl.BlockSpec((g.P, rows_k, store_w),
-                                      lambda n, t, i: (n, i, t)))
+        out_specs.append(pl.BlockSpec((g.P, None, rows_k, store_w),
+                                      lambda n, t, i: (n, i, 0, t)))
         out_shapes.append(jax.ShapeDtypeStruct(
-            (N + g.n_pad, g.n_bands * rows_k, g.n_tiles * store_w), bdt))
+            (N + g.n_pad, g.n_bands, rows_k, g.n_tiles * store_w), bdt))
         crops.append((h_k, w_k, crop_off))
 
+    # overlapping windows: element-offset blocks (row step mult0, column
+    # step tile_w; Pallas needs every dim of such a block element-indexed)
     outs = pl.pallas_call(
         kernel,
         grid=((N + g.n_pad) // g.P, g.n_tiles, g.n_bands),
-        in_specs=[pl.BlockSpec((g.P, g.r_window, g.wpt),
+        in_specs=[pl.BlockSpec((pl.Element(g.P), pl.Element(g.r_block),
+                                pl.Element(g.wpt)),
                                lambda n, t, i: (n * g.P, i * g.mult0,
-                                                t * g.tile_w),
-                               indexing_mode=pl.Unblocked())] + w_specs,
+                                                t * g.tile_w))] + w_specs,
         out_specs=out_specs,
         out_shape=out_shapes,
         scratch_shapes=[pltpu.VMEM(shape, dt) for shape, dt in g.ring_shapes],
@@ -476,7 +565,7 @@ def launch(planes: Array, stages, geom, vc, kernel) -> tuple:
     )(x, *w_args)
     if not isinstance(outs, (list, tuple)):
         outs = (outs,)
-    return tuple(o[:N, :h_k, c0:c0 + w_k]
+    return tuple(o.reshape(o.shape[0], -1, o.shape[-1])[:N, :h_k, c0:c0 + w_k]
                  for o, (h_k, w_k, c0) in zip(outs, crops))
 
 
@@ -487,5 +576,6 @@ def execute(planes: Array, stages, geom, vc) -> tuple:
     kernel = functools.partial(window_kernel, plan=geom.plan,
                                carrier=planes.dtype, interp=vc.run_interpret,
                                n_out=len(geom.outs),
-                               store_slices=store_slices)
+                               store_slices=store_slices,
+                               r_window=geom.r_window)
     return launch(planes, stages, geom, vc, kernel)

@@ -7,7 +7,10 @@ ladder (the CI mode matrix pins the whole suite to one plan through
 REPRO_FUSED_MODE env var).  `run_ladder` is the rung loop every driver
 entry uses: any rung failure except ValueError (chain misconfiguration
 always surfaces) degrades to the next rung with a recorded
-`core.faultinject` event; only the FINAL rung's failure raises."""
+`core.faultinject` event; only the FINAL rung's failure raises.
+NotImplementedError (a stage the plan cannot lower on this device) also
+always raises: a rung that cannot express the chain is not a fault to
+degrade around."""
 from __future__ import annotations
 
 # every execution plan, fastest-first: streaming (row-carry rings, one
@@ -90,17 +93,18 @@ def resolve_rungs(mode: str, ladder) -> tuple[str, ...]:
 
 
 def run_ladder(rungs, run, *, stage: str, detail: str):
-    """Try each rung in order: ValueError always propagates (chain
-    misconfiguration must surface from every plan), any other failure
-    degrades to the next rung with a recorded `core.faultinject` event,
-    and the final rung's failure raises."""
+    """Try each rung in order: ValueError and NotImplementedError always
+    propagate (chain misconfiguration, or a stage this device cannot
+    lower, must surface from every plan), any other failure degrades to
+    the next rung with a recorded `core.faultinject` event, and the final
+    rung's failure raises."""
     from repro.core import faultinject
 
     for i, rung in enumerate(rungs):
         try:
             return run(rung)
-        except ValueError:
-            raise           # chain misconfiguration: every plan must surface it
+        except (ValueError, NotImplementedError):
+            raise           # misconfiguration / unlowerable: never masked
         except Exception as e:
             if i == len(rungs) - 1:
                 raise

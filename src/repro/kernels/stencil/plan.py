@@ -50,6 +50,16 @@ from .ir import _GATHER_OPS, _STRIDES, WIDENING_OPS, _affine_disp_over, \
 
 LMULS = (8, 4, 2, 1)
 
+# rows of one native (sublane, 128-lane) tile by element width: a VMEM
+# block's second-minor extent must be a multiple of this for Mosaic
+_SUBLANE_TILE = {1: 32, 2: 16, 4: 8}
+
+
+def round_sublanes(rows: int, dtype) -> int:
+    """`rows` rounded up to the dtype's native sublane tile."""
+    t = _SUBLANE_TILE[jnp.dtype(dtype).itemsize]
+    return rows + (-rows) % t
+
 
 @dataclass(frozen=True)
 class WorkingSet:
@@ -463,7 +473,9 @@ class ChainGeom:
     n_tiles: int           # column-tile grid extent
     tile_w: int            # tile interior width, input resolution (W untiled)
     mult0: int             # input-window row step per band
-    r_window: int          # input-window rows
+    r_window: int          # input-window rows the chain consumes
+    r_block: int           # r_window rounded up to the sublane tile (the
+    #                        DMA'd block; the extra rows are never read)
     pad_top: int           # rows of replicate pad above the image
     t_rows: int            # padded input height
     pw_l: int              # left column pad (stride-aligned accumulated halo)
@@ -539,7 +551,8 @@ def build_chain_geom(stages, shape: tuple, dtype, vc, *, stream: bool = False,
     mult0, off0, r_window = iface[0]
     pad_top = -off0
     n_bands = max(1, -(-h_fin // rows))
-    t_rows = (n_bands - 1) * mult0 + r_window
+    r_block = round_sublanes(r_window, dtype)
+    t_rows = (n_bands - 1) * mult0 + r_block
 
     # column geometry, per tile: left pad divisible by the total downsample
     # product so in-kernel even-index decimation lands on even *image*
@@ -547,8 +560,10 @@ def build_chain_geom(stages, shape: tuple, dtype, vc, *, stream: bool = False,
     # origin, so tile t's block starts at input column t*tile_w of the
     # padded array (whose column 0 is image column -pw_l)
     pw_l = pw_in + (-pw_in) % down_x
+    # (a strided chain pads to lane * stride, so every decimated band
+    # stays a whole number of lanes wide)
     wpt = pw_l + tile_w + pw_in
-    wpt += (-wpt) % vc.lane
+    wpt += (-wpt) % (vc.lane * down_x)
     pad_w = (n_tiles - 1) * tile_w + wpt
 
     # (row, col) halo still needed *after* each stage, at its output
@@ -711,6 +726,7 @@ def build_chain_geom(stages, shape: tuple, dtype, vc, *, stream: bool = False,
 
     return ChainGeom(P=P, n_pad=n_pad, n_bands=n_bands, n_tiles=n_tiles,
                      tile_w=tile_w, mult0=mult0, r_window=r_window,
+                     r_block=r_block,
                      pad_top=pad_top, t_rows=t_rows, pw_l=pw_l, wpt=wpt,
                      pad_w=pad_w, plan=plan, splan=splan,
                      ring_shapes=tuple(ring_shapes), outs=tuple(outs))

@@ -7,19 +7,12 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 *before* importing jax.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types; older versions default to Auto
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis_types when the installed jax supports it
-    (jax < 0.5 has no jax.sharding.AxisType and defaults to Auto)."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """jax.make_mesh with Auto axis types on every axis."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -28,7 +28,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import shard_map
 
 from .layers import ACTIVATIONS, dense_init
 
@@ -173,11 +172,11 @@ def _moe_ffn_a2a(p, x: Array, cfg, plan) -> tuple[Array, dict]:
     ep_spec = P(a2a if len(a2a) > 1 else a2a[0], None, None)
     x_spec = P(plan["bdp"], "model", None)
     rb = p.get("router_bias")
-    out, mets, load = shard_map(
+    out, mets, load = jax.shard_map(
         local_fn, mesh=plan["mesh"],
         in_specs=(x_spec, P(None, None),
                   (P(None) if rb is not None else None), ep_spec, ep_spec, ep_spec),
-        out_specs=(x_spec, P(), P()),
+        out_specs=(x_spec, P(), P()), check_vma=False,
     )(x, p["router"], rb, p["w_gate"], p["w_up"], p["w_down"])
     metrics = {"moe_aux": mets[0], "moe_z": mets[1], "moe_drop_frac": mets[2],
                "expert_load": load}

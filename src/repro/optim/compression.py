@@ -15,7 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.compat import shard_map
 
 Array = jax.Array
 
@@ -75,8 +74,10 @@ def make_compressed_allreduce(mesh, axis_name="data"):
     def one(g, r):
         def body(gl, rl):
             return compressed_psum(gl, rl, axis_name)
-        return shard_map(body, mesh=mesh, in_specs=(P(axis_name), P(axis_name)),
-                         out_specs=(P(axis_name), P(axis_name)))(g, r)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(axis_name), P(axis_name)),
+                             out_specs=(P(axis_name), P(axis_name)),
+                             check_vma=False)(g, r)
 
     def allreduce(tree, residuals):
         out = jax.tree.map(one, tree, residuals)

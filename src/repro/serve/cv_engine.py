@@ -260,7 +260,7 @@ class CvEngine:
             for attempt in range(self.max_retries + 1):
                 try:
                     return self._run_batch(batch, rung), rung, retries, events
-                except ValueError:
+                except (ValueError, NotImplementedError):
                     raise            # misconfiguration: no rung may mask it
                 except Exception as e:
                     injected = isinstance(e, faultinject.InjectedFault)
@@ -629,4 +629,6 @@ if __name__ == "__main__":          # python -m repro.serve.cv_engine --smoke
                          "REPRO_FAULT_SPEC) and exit nonzero on failure")
     a = ap.parse_args()
     if a.smoke:
+        from repro.core import jaxcache
+        jaxcache.enable()
         raise SystemExit(_smoke())

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat, faultinject
+from repro.core import faultinject
 from repro.kernels.stencil import DEGRADATION_LADDER, MODES
 from repro.kernels.stencil.ladder import resolve_rungs
 from repro.serve.health import (CircuitBreaker, DeviceHealthLedger,
@@ -231,9 +231,9 @@ class ShardDispatcher:
             out_specs = jax.tree.map(
                 lambda s: P("data", *([None] * (len(s.shape) - 1))),
                 out_shape)
-            self._coll_cache[key] = jax.jit(compat.shard_map(
+            self._coll_cache[key] = jax.jit(jax.shard_map(
                 f1, mesh=self.mesh, in_specs=(in_spec,),
-                out_specs=out_specs))
+                out_specs=out_specs, check_vma=False))
         return self._coll_cache[key]
 
     def _collective_eligible(self, n: int) -> bool:

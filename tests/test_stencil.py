@@ -720,10 +720,8 @@ def test_gather_and_pyr_up_working_set():
 
 
 def test_count_pallas_calls_compat():
-    """count_pallas_calls walks jaxprs via core.compat (jax.extend.core on
-    new jax, jax.core fallback) — and sees through nested jits."""
-    from repro.core import compat
-    assert compat.ClosedJaxpr is not None and compat.Jaxpr is not None
+    """count_pallas_calls walks jaxprs through jax.extend.core's public
+    ClosedJaxpr/Jaxpr types — and sees through nested jits."""
     img = jnp.zeros((32, 32), jnp.uint8)
     inner = jax.jit(lambda x: stencil.fused_chain(
         x, (stencil.gaussian_stage(3),), vc=VectorConfig(lmul=1)))
