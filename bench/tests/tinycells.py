@@ -1,0 +1,31 @@
+"""Cells of BENCHMARK.json cut to a size the CPU can run in a test:
+48x128 frames with room for 256 keypoints (such a frame has some tens),
+2 frames per submit (8 on four virtual devices), the Pallas kernels
+interpreted."""
+from __future__ import annotations
+
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (BENCH, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+H, W, MAX_KP = 48, 128, 256
+
+
+def cell(name: str, *, batch: int = 2, pool: int = 4, workload=None):
+    from benchlib import spec
+    c = spec.Cell(name, ROOT, workload=workload)
+    cfg = dict(c.config, frame=dict(c.config["frame"], height=H, width=W),
+               max_kp=MAX_KP)
+    return spec.Cell(name, ROOT, config=cfg, workload=workload,
+                     traffic=dict(c.traffic, batch=batch, pool=pool))
+
+
+# the four-chip cell of `bench/traffic/b64x4.json`, not yet proven on the
+# chip and so not in BENCHMARK.json
+FOUR_CHIPS = {"name": "sift1080p.b64x4", "config": "sift1080p",
+              "traffic": "b64x4", "chips": 4}
