@@ -45,12 +45,13 @@ sharp_k = jnp.asarray([[0, -1, 0], [-1, 5, -1], [0, -1, 0]], jnp.float32)
 chain = (stencil.gaussian_stage(5), stencil.filter_stage(sharp_k),
          stencil.erode_stage(1))
 batch = jnp.stack([crop, crop])[..., None]            # (B, H, W, C)
-stencil.reset_launch_counter()
 t0 = time.perf_counter()
 fused = stencil.fused_chain(batch, chain, vc=None)    # chain-aware autotune
 jax.block_until_ready(fused)
+n_calls = stencil.count_pallas_calls(
+    lambda x: stencil.fused_chain(x, chain, vc=None), batch)
 print(f"fused 3-stage chain on {tuple(batch.shape)}: "
-      f"{time.perf_counter()-t0:.3f}s, {stencil.launch_count()} kernel launch")
+      f"{time.perf_counter()-t0:.3f}s, {n_calls} kernel launch")
 oracle = ref.chain_ref(batch, chain)
 # u8 saturate_cast tolerance: XLA's mul+add vs fused-multiply-add codegen
 # can differ by 1 ulp at .5 rounding boundaries

@@ -6,11 +6,15 @@ module is imported.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads
 it itself and this sets no other directory; otherwise the cache lives at
 a fixed ``<checkout>/.jax_cache`` (the path is part of what a later run
 must find again).  `counts()` reports compile requests against cache
-hits, so a run can show whether it compiled or reused.
+hits, so a run can show whether it compiled or reused; each request also
+leaves an instant ``jaxcache.request`` span in a profiler capture, so a
+trace counts them per ``serve.submit`` span.
 """
 from __future__ import annotations
 
 import os
+
+from jax.profiler import TraceAnnotation
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))))
@@ -26,6 +30,9 @@ def _on_event(event: str, **_kw) -> None:
     key = _EVENTS.get(event)
     if key is not None:
         _COUNTS[key] += 1
+    if key == "requests":
+        with TraceAnnotation("jaxcache.request"):
+            pass
 
 
 def enable() -> str:

@@ -83,8 +83,7 @@ modes charge the strictly smaller ring footprint), with the tiled2d tile
 width picked jointly (`plan.pick_tile_plan`)."""
 
 from . import exec_ref, exec_streaming, exec_window, ir, ladder, plan  # noqa: F401
-from .driver import (chained_launches, count_pallas_calls, fused_chain,  # noqa: F401
-                     launch_count, reset_launch_counter)
+from .driver import chained_launches, count_pallas_calls, fused_chain  # noqa: F401
 from .exec_window import _apply_morph  # noqa: F401  (erode.py + tests use it)
 from .ir import (Stage, _GATHER_OPS, _N_WEIGHTS, _STRIDES,  # noqa: F401
                  _UPSAMPLES, WIDENING_OPS, affine_disp_bound, affine_stage,

@@ -26,20 +26,6 @@ from .plan import build_chain_geom, chain_accumulated_halo
 
 Array = jax.Array
 
-# pallas_call launches issued by this package (one per fused_chain
-# invocation; the jitted program of one invocation contains exactly one
-# pallas_call — see count_pallas_calls for the jaxpr-level check).
-_LAUNCHES = 0
-
-
-def reset_launch_counter() -> None:
-    global _LAUNCHES
-    _LAUNCHES = 0
-
-
-def launch_count() -> int:
-    return _LAUNCHES
-
 
 def count_pallas_calls(fn, *args, **kwargs) -> int:
     """Number of pallas_call equations in fn's jaxpr (recursing into calls)."""
@@ -187,9 +173,6 @@ def fused_chain(img: Array, stages, *, vc=None, mode: str | None = None,
         if vck is None:
             vck = plan_mod.pick_chain_lmul(stages, w_in, in_dtype=img.dtype,
                                            streaming=stream)
-
-        global _LAUNCHES
-        _LAUNCHES += 1
 
         spec, weights = spec_of(stages), flat_weights(stages)
         if img.ndim == 2:

@@ -52,6 +52,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import faultinject
 from repro.core import autotune
@@ -142,6 +143,7 @@ class CvEngine:
             dispatcher = ShardDispatcher(mesh, ladder=ladder)
         self.dispatcher = dispatcher
         self.captured: list = []     # (bucket, canonical batch) when capturing
+        self._submits = 0            # index of the last submit, for its span
         self.stats = {"served": 0, "errors": 0, "degraded_batches": 0,
                       "retries": 0, "deadline_missed": 0, "sanitized": 0,
                       "sharded_batches": 0, "shard_failures": 0}
@@ -240,9 +242,14 @@ class CvEngine:
 
     def _run_batch(self, batch: np.ndarray, rung: str):
         """One canonical batch through the pipeline at one explicit rung."""
-        out = self._batch_fn(jnp.asarray(batch), rung)
-        return {k: np.asarray(jax.block_until_ready(v))
-                for k, v in out.items()}
+        with TraceAnnotation("serve.h2d"):
+            x = jnp.asarray(batch)
+        with TraceAnnotation("serve.dispatch"):
+            out = self._batch_fn(x, rung)
+        with TraceAnnotation("serve.wait"):
+            out = jax.block_until_ready(out)
+        with TraceAnnotation("serve.d2h"):
+            return {k: np.asarray(v) for k, v in out.items()}
 
     def _run_ladder(self, batch: np.ndarray, deadlines=()):
         """Ladder + bounded retry; returns (result, plan, retries, events)
@@ -338,77 +345,92 @@ class CvEngine:
             return None
 
     def submit(self, workload) -> list[Response]:
-        """Serve a workload (arrays or `Request`s) -> one Response each."""
-        t_all = time.monotonic()
-        reqs = [r if isinstance(r, Request) else Request(r) for r in workload]
-        responses: list[Response | None] = [None] * len(reqs)
+        """Serve a workload (arrays or `Request`s) -> one Response each.
 
-        # admission + bucketing
-        groups: dict = {}
-        for idx, req in enumerate(reqs):
-            if req.deadline is not None and time.monotonic() > req.deadline:
-                self.stats["deadline_missed"] += 1
-                responses[idx] = Response(index=idx, ok=False,
-                                          deadline_missed=True,
-                                          error="deadline_exceeded")
-                continue
-            arr, admitted = self._admit(req, idx)
-            if arr is None:
-                responses[idx] = admitted           # error Response
-                continue
-            bucket = self._bucket_of(arr.shape)
-            canon = self._pad_to(arr, bucket)
-            gkey = (bucket or canon.shape[:2], canon.shape, str(canon.dtype))
-            groups.setdefault(gkey, []).append((idx, canon, admitted))
+        Each phase runs under a `jax.profiler.TraceAnnotation`, so a
+        profiler capture of a serving process shows them beside the
+        device ops: ``serve.submit`` (the whole call, keyword ``submit``:
+        its index) holds ``serve.admit`` (admission, bucketing, padding),
+        then per group batch ``serve.stack``, ``serve.h2d`` (copy to the
+        device), ``serve.dispatch`` (trace or cache lookup, enqueue),
+        ``serve.wait`` (the device computing), ``serve.d2h`` (the fetch)
+        and ``serve.assemble`` (the Responses); the sharded path puts
+        ``serve.fanout`` around its transfers and dispatch."""
+        self._submits += 1
+        with TraceAnnotation("serve.submit", submit=self._submits):
+            reqs = [r if isinstance(r, Request) else Request(r) for r in workload]
+            responses: list[Response | None] = [None] * len(reqs)
+            groups: dict = {}
+            with TraceAnnotation("serve.admit"):
+                for idx, req in enumerate(reqs):
+                    if req.deadline is not None and time.monotonic() > req.deadline:
+                        self.stats["deadline_missed"] += 1
+                        responses[idx] = Response(index=idx, ok=False,
+                                                  deadline_missed=True,
+                                                  error="deadline_exceeded")
+                        continue
+                    arr, admitted = self._admit(req, idx)
+                    if arr is None:
+                        responses[idx] = admitted       # error Response
+                        continue
+                    bucket = self._bucket_of(arr.shape)
+                    canon = self._pad_to(arr, bucket)
+                    gkey = (bucket or canon.shape[:2], canon.shape, str(canon.dtype))
+                    groups.setdefault(gkey, []).append((idx, canon, admitted))
 
-        # batched execution: sharded fan-out when a multi-device dispatcher
-        # is attached, local ladder otherwise
-        sharded = self.dispatcher is not None and self.dispatcher.n_shards > 1
-        for (bucket, _, _), members in groups.items():
-            for lo in range(0, len(members), self.max_batch):
-                part = members[lo:lo + self.max_batch]
-                idxs = [m[0] for m in part]
-                batch = np.stack([m[1] for m in part])
-                if self.capture_frames:
-                    self.captured.append((tuple(bucket), batch))
-                t0 = time.monotonic()
-                if sharded:
-                    self._submit_sharded(part, idxs, batch, bucket, reqs,
-                                         responses, t0)
-                    continue
-                try:
-                    result, plan, retries, events = self._run_ladder(
-                        batch, [reqs[idx].deadline for idx in idxs])
-                except ValueError:
-                    raise            # caller bug, not a serving fault
-                except Exception as e:
-                    for idx in idxs:
-                        responses[idx] = Response(
-                            index=idx, ok=False, bucket=tuple(bucket),
-                            error=f"floor_rung_failed: {type(e).__name__}: {e}",
-                            events=[ev for _, _, evs in part for ev in evs])
-                        self.stats["errors"] += 1
-                    continue
-                dt = time.monotonic() - t0
-                degraded = plan != self.ladder[0] or bool(events)
-                if degraded:
-                    self.stats["degraded_batches"] += 1
-                for k, idx in enumerate(idxs):
-                    admit_events = part[k][2]
-                    missed = self._deadline_missed(reqs[idx], idx)
-                    responses[idx] = Response(
-                        index=idx, ok=True,
-                        desc=result["desc"][k] if "desc" in result else None,
-                        valid=result["valid"][k] if "valid" in result else None,
-                        pred=(int(result["pred"][k])
-                              if "pred" in result else None),
-                        bucket=tuple(bucket), plan=plan, retries=retries,
-                        degraded=degraded, deadline_missed=missed,
-                        events=list(admit_events) + list(events),
-                        latency_s=dt)
-                    self.stats["served"] += 1
-        self.stats["last_submit_s"] = time.monotonic() - t_all
-        return responses  # responses[i] is never None past this point
+            # batched execution: sharded fan-out when a multi-device dispatcher
+            # is attached, local ladder otherwise
+            sharded = self.dispatcher is not None and self.dispatcher.n_shards > 1
+            for (bucket, _, _), members in groups.items():
+                for lo in range(0, len(members), self.max_batch):
+                    part = members[lo:lo + self.max_batch]
+                    idxs = [m[0] for m in part]
+                    with TraceAnnotation("serve.stack"):
+                        batch = np.stack([m[1] for m in part])
+                    if self.capture_frames:
+                        self.captured.append((tuple(bucket), batch))
+                    t0 = time.monotonic()
+                    if sharded:
+                        self._submit_sharded(part, idxs, batch, bucket, reqs, responses, t0)
+                    else:
+                        self._submit_local(part, idxs, batch, bucket, reqs, responses, t0)
+            return responses  # responses[i] is never None past this point
+
+    def _submit_local(self, part, idxs, batch, bucket, reqs,
+                      responses, t0) -> None:
+        """One group batch through the local ladder."""
+        try:
+            result, plan, retries, events = self._run_ladder(
+                batch, [reqs[idx].deadline for idx in idxs])
+        except ValueError:
+            raise            # caller bug, not a serving fault
+        except Exception as e:
+            for idx in idxs:
+                responses[idx] = Response(
+                    index=idx, ok=False, bucket=tuple(bucket),
+                    error=f"floor_rung_failed: {type(e).__name__}: {e}",
+                    events=[ev for _, _, evs in part for ev in evs])
+                self.stats["errors"] += 1
+            return
+        dt = time.monotonic() - t0
+        degraded = plan != self.ladder[0] or bool(events)
+        if degraded:
+            self.stats["degraded_batches"] += 1
+        with TraceAnnotation("serve.assemble"):
+            for k, idx in enumerate(idxs):
+                admit_events = part[k][2]
+                missed = self._deadline_missed(reqs[idx], idx)
+                responses[idx] = Response(
+                    index=idx, ok=True,
+                    desc=result["desc"][k] if "desc" in result else None,
+                    valid=result["valid"][k] if "valid" in result else None,
+                    pred=(int(result["pred"][k])
+                          if "pred" in result else None),
+                    bucket=tuple(bucket), plan=plan, retries=retries,
+                    degraded=degraded, deadline_missed=missed,
+                    events=list(admit_events) + list(events),
+                    latency_s=dt)
+                self.stats["served"] += 1
 
     def _deadline_missed(self, req: Request, idx: int) -> bool:
         missed = (req.deadline is not None
@@ -442,35 +464,36 @@ class CvEngine:
         dt = time.monotonic() - t0
         self.stats["sharded_batches"] += 1
         degraded_batch = False
-        for k, idx in enumerate(idxs):
-            admit_events = list(part[k][2])
-            sres, row = report.result_of(k)
-            events = admit_events + list(report.events) + list(sres.events)
-            if not sres.ok:
-                self.stats["errors"] += 1
-                self.stats["shard_failures"] += 1
+        with TraceAnnotation("serve.assemble"):
+            for k, idx in enumerate(idxs):
+                admit_events = list(part[k][2])
+                sres, row = report.result_of(k)
+                events = admit_events + list(report.events) + list(sres.events)
+                if not sres.ok:
+                    self.stats["errors"] += 1
+                    self.stats["shard_failures"] += 1
+                    responses[idx] = Response(
+                        index=idx, ok=False, bucket=tuple(bucket),
+                        shard=sres.shard, device=sres.device,
+                        error=f"shard_failed: {sres.error}", events=events)
+                    continue
+                degraded = (sres.plan != self.ladder[0] or sres.redispatches > 0
+                            or bool(events))
+                degraded_batch = degraded_batch or degraded
+                missed = self._deadline_missed(reqs[idx], idx)
                 responses[idx] = Response(
-                    index=idx, ok=False, bucket=tuple(bucket),
-                    shard=sres.shard, device=sres.device,
-                    error=f"shard_failed: {sres.error}", events=events)
-                continue
-            degraded = (sres.plan != self.ladder[0] or sres.redispatches > 0
-                        or bool(events))
-            degraded_batch = degraded_batch or degraded
-            missed = self._deadline_missed(reqs[idx], idx)
-            responses[idx] = Response(
-                index=idx, ok=True,
-                desc=(sres.value["desc"][row]
-                      if "desc" in sres.value else None),
-                valid=(sres.value["valid"][row]
-                       if "valid" in sres.value else None),
-                pred=(int(sres.value["pred"][row])
-                      if "pred" in sres.value else None),
-                bucket=tuple(bucket), plan=sres.plan,
-                retries=sres.redispatches, degraded=degraded,
-                deadline_missed=missed, shard=sres.shard,
-                device=sres.device, events=events, latency_s=dt)
-            self.stats["served"] += 1
+                    index=idx, ok=True,
+                    desc=(sres.value["desc"][row]
+                          if "desc" in sres.value else None),
+                    valid=(sres.value["valid"][row]
+                           if "valid" in sres.value else None),
+                    pred=(int(sres.value["pred"][row])
+                          if "pred" in sres.value else None),
+                    bucket=tuple(bucket), plan=sres.plan,
+                    retries=sres.redispatches, degraded=degraded,
+                    deadline_missed=missed, shard=sres.shard,
+                    device=sres.device, events=events, latency_s=dt)
+                self.stats["served"] += 1
         if degraded_batch:
             self.stats["degraded_batches"] += 1
 

@@ -53,6 +53,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.core import faultinject
@@ -356,101 +357,106 @@ class ShardDispatcher:
         Returns a DispatchReport; raises only ValueError (caller bug).
         Requests of a shard whose every option failed come back with that
         ShardResult's ok=False — the rest of the batch stands."""
-        batch = np.asarray(batch)
-        B = batch.shape[0]
-        if B == 0:
-            raise ValueError("dispatch: empty batch")
-        self.stats["dispatches"] += 1
-        self.health.tick()
-        n = min(self.n_shards, B)
-        pad = (-B) % n
-        if pad:
-            batch = np.concatenate([batch, batch[-1:].repeat(pad, axis=0)])
-        per = batch.shape[0] // n
-        shard_np = [batch[i * per:(i + 1) * per] for i in range(n)]
-        base_key = (signature, tuple(bucket) if bucket else None)
-        walk = resolve_rungs(mode if mode is not None else self.ladder[0],
-                             self.ladder)
+        with TraceAnnotation("serve.fanout"):
+            batch = np.asarray(batch)
+            B = batch.shape[0]
+            if B == 0:
+                raise ValueError("dispatch: empty batch")
+            self.stats["dispatches"] += 1
+            self.health.tick()
+            n = min(self.n_shards, B)
+            pad = (-B) % n
+            if pad:
+                batch = np.concatenate([batch, batch[-1:].repeat(pad, axis=0)])
+            per = batch.shape[0] // n
+            shard_np = [batch[i * per:(i + 1) * per] for i in range(n)]
+            base_key = (signature, tuple(bucket) if bucket else None)
+            walk = resolve_rungs(mode if mode is not None else self.ladder[0],
+                                 self.ladder)
 
-        results: list[ShardResult | None] = [None] * n
-        pending = list(range(n))
-        report_events: list = []
+            results: list[ShardResult | None] = [None] * n
+            pending = list(range(n))
+            report_events: list = []
 
-        # -- collective fast path: one shard_map launch over the mesh
-        if self._collective_eligible(n):
-            rungs, skip_evs = self.breaker.filter_rungs(base_key, walk)
-            rung0 = rungs[0]
-            with faultinject.collect_events() as cev:
-                try:
-                    for d in self.devices:
-                        self._check_device(d)
-                    faultinject.maybe_raise(
-                        "collective_timeout",
-                        site=f"collective:{signature}")
-                    t0 = time.monotonic()
-                    out = self._collective_fn(
-                        fn, rung0, batch.shape, batch.dtype)(
-                            jax.device_put(
+            # -- collective fast path: one shard_map launch over the mesh
+            if self._collective_eligible(n):
+                rungs, skip_evs = self.breaker.filter_rungs(base_key, walk)
+                rung0 = rungs[0]
+                with faultinject.collect_events() as cev:
+                    try:
+                        for d in self.devices:
+                            self._check_device(d)
+                        faultinject.maybe_raise(
+                            "collective_timeout",
+                            site=f"collective:{signature}")
+                        t0 = time.monotonic()
+                        with TraceAnnotation("serve.h2d"):
+                            x = jax.device_put(
                                 jnp.asarray(batch),
                                 rules.cv_batch_sharding(self.mesh,
-                                                        batch.ndim)))
-                    out = {k: np.asarray(jax.block_until_ready(v))
-                           for k, v in out.items()}
-                    dt = time.monotonic() - t0
-                    pending = []
-                    for i in range(n):
-                        sl = {k: v[i * per:(i + 1) * per]
-                              for k, v in out.items()}
-                        bad = _poisoned_fields(sl)
-                        if bad:
-                            self.stats["poisoned_shards"] += 1
-                            self.breaker.record_failure(
+                                                        batch.ndim))
+                        with TraceAnnotation("serve.dispatch"):
+                            out = self._collective_fn(
+                                fn, rung0, batch.shape, batch.dtype)(x)
+                        with TraceAnnotation("serve.wait"):
+                            out = jax.block_until_ready(out)
+                        with TraceAnnotation("serve.d2h"):
+                            out = {k: np.asarray(v) for k, v in out.items()}
+                        dt = time.monotonic() - t0
+                        pending = []
+                        for i in range(n):
+                            sl = {k: v[i * per:(i + 1) * per]
+                                  for k, v in out.items()}
+                            bad = _poisoned_fields(sl)
+                            if bad:
+                                self.stats["poisoned_shards"] += 1
+                                self.breaker.record_failure(
+                                    tuple(base_key) + (rung0,))
+                                faultinject.record_degradation(
+                                    stage="dispatch", from_plan="collective",
+                                    to_plan="isolated",
+                                    reason=f"shard output poisoned "
+                                           f"({','.join(bad)}): isolated retry",
+                                    detail=f"shard {i}")
+                                pending.append(i)
+                                continue
+                            self.health.record_success(self.devices[i], dt)
+                            results[i] = ShardResult(
+                                shard=i, ok=True, value=sl, plan=rung0,
+                                device=device_key(self.devices[i]),
+                                collective=True, latency_s=dt)
+                        if len(pending) < n:
+                            self.breaker.record_success(
                                 tuple(base_key) + (rung0,))
-                            faultinject.record_degradation(
-                                stage="dispatch", from_plan="collective",
-                                to_plan="isolated",
-                                reason=f"shard output poisoned "
-                                       f"({','.join(bad)}): isolated retry",
-                                detail=f"shard {i}")
-                            pending.append(i)
-                            continue
-                        self.health.record_success(self.devices[i], dt)
-                        results[i] = ShardResult(
-                            shard=i, ok=True, value=sl, plan=rung0,
-                            device=device_key(self.devices[i]),
-                            collective=True, latency_s=dt)
-                    if len(pending) < n:
-                        self.breaker.record_success(
-                            tuple(base_key) + (rung0,))
-                        self.stats["collective_batches"] += 1
-                except ValueError:
-                    raise
-                except Exception as e:
-                    faultinject.record_degradation(
-                        stage="dispatch", from_plan="collective",
-                        to_plan="isolated",
-                        reason=f"collective fan-out failed: "
-                               f"{type(e).__name__}: {e}",
-                        detail=f"{signature}|{n} shards",
-                        injected=isinstance(e, faultinject.InjectedFault))
-                    pending = list(range(n))
-            report_events.extend(skip_evs)
-            report_events.extend(
-                ev for ev in cev if ev not in report_events)
+                            self.stats["collective_batches"] += 1
+                    except ValueError:
+                        raise
+                    except Exception as e:
+                        faultinject.record_degradation(
+                            stage="dispatch", from_plan="collective",
+                            to_plan="isolated",
+                            reason=f"collective fan-out failed: "
+                                   f"{type(e).__name__}: {e}",
+                            detail=f"{signature}|{n} shards",
+                            injected=isinstance(e, faultinject.InjectedFault))
+                        pending = list(range(n))
+                report_events.extend(skip_evs)
+                report_events.extend(
+                    ev for ev in cev if ev not in report_events)
 
-        # -- isolated fault domains: sequential dispatch (deterministic
-        # fault replay), per-device async compute
-        if pending:
-            healthy = self.health.healthy_devices()
-            for i in pending:
-                rungs, skip_evs = self.breaker.filter_rungs(base_key, walk)
-                dev = (healthy[i % len(healthy)] if healthy
-                       else self.devices[i % self.n_shards])
-                results[i] = self._run_isolated(
-                    i, shard_np[i], fn, rungs, base_key, dev)
-                results[i].events = list(skip_evs) + results[i].events
-                self.stats["isolated_shards"] += 1
+            # -- isolated fault domains: sequential dispatch (deterministic
+            # fault replay), per-device async compute
+            if pending:
                 healthy = self.health.healthy_devices()
+                for i in pending:
+                    rungs, skip_evs = self.breaker.filter_rungs(base_key, walk)
+                    dev = (healthy[i % len(healthy)] if healthy
+                           else self.devices[i % self.n_shards])
+                    results[i] = self._run_isolated(
+                        i, shard_np[i], fn, rungs, base_key, dev)
+                    results[i].events = list(skip_evs) + results[i].events
+                    self.stats["isolated_shards"] += 1
+                    healthy = self.health.healthy_devices()
 
-        return DispatchReport(batch=B, n_shards=n, shard_size=per,
-                              shards=results, events=report_events)
+            return DispatchReport(batch=B, n_shards=n, shard_size=per,
+                                  shards=results, events=report_events)

@@ -1,8 +1,8 @@
 """What keeps a CPU run from passing for a device run: kernels interpret
 only on the CPU backend, a stage the TPU cannot lower raises through every
 ladder, peaks exist only for known chips, the compile cache honors
-``JAX_COMPILATION_CACHE_DIR``, and seeded frames are the same in every
-process."""
+``JAX_COMPILATION_CACHE_DIR`` and marks each compile request in a profiler
+capture, and seeded frames are the same in every process."""
 import os
 import subprocess
 import sys
@@ -82,6 +82,33 @@ def test_compile_cache_dir(tmp_path, env_dir):
     assert ok == "True"
     assert path == (str(tmp_path) if env_dir
                     else os.path.join(ROOT, ".jax_cache"))
+
+
+def test_compile_requests_leave_marks(tmp_path):
+    """Each compile request `jaxcache` counts leaves one instant
+    `jaxcache.request` span in a profiler capture."""
+    code = f"""
+        import glob
+        import jax, jax.numpy as jnp
+        from jax.profiler import ProfileData
+        from repro.core import jaxcache
+        jaxcache.enable()
+        x = jnp.ones(5)
+        c0 = jaxcache.counts()["requests"]
+        with jax.profiler.trace({str(tmp_path / "trace")!r}):
+            jax.jit(lambda v: v * 3)(x).block_until_ready()
+            jax.jit(lambda v: v - 2)(x).block_until_ready()
+        n = jaxcache.counts()["requests"] - c0
+        path, = glob.glob({str(tmp_path / "trace")!r} + "/**/*.xplane.pb",
+                          recursive=True)
+        marks = sum(e.name == "jaxcache.request"
+                    for p in ProfileData.from_file(path).planes
+                    for line in p.lines for e in line.events)
+        print(n, marks)
+    """
+    n, marks = map(int, _run(code, JAX_COMPILATION_CACHE_DIR=str(
+        tmp_path / "cache")).split())
+    assert n >= 2 and marks == n
 
 
 def test_image_stream_same_in_every_process():

@@ -43,8 +43,8 @@ STENCIL_PUBLIC_API = (
     "default_chain_mode", "default_ladder", "dilate_stage", "driver",
     "erode_stage", "exec_ref", "exec_streaming", "exec_window",
     "filter_stage", "fused_chain", "gaussian_stage", "grad_stage", "ir",
-    "ladder", "launch_count", "plan", "pyr_down_stage", "pyr_up_stage",
-    "remap_stage", "reset_launch_counter", "resize2_stage",
+    "ladder", "plan", "pyr_down_stage", "pyr_up_stage",
+    "remap_stage", "resize2_stage",
     "resolve_chain", "resolve_rungs", "sep_filter_stage",
     "set_default_chain_mode",
     "set_default_ladder", "sobel_stage", "stage_out_hw",
