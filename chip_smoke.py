@@ -153,7 +153,7 @@ def phase_extract(imgs) -> dict:
     _served(res, "extract")
     _no_degradation("extract")
 
-    fn = lambda x: eng._batch_fn(x, "streaming")            # noqa: E731
+    fn = lambda x: eng._batch_body(x, "streaming")          # noqa: E731
     n_pallas = stencil.count_pallas_calls(fn, jnp.asarray(batch))
     check(n_pallas > 0, "extract: the served program has no pallas_call")
     n_tcc = jax.jit(fn).lower(jnp.asarray(batch)).as_text().count(

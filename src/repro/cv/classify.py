@@ -205,6 +205,13 @@ class ClassifyPlan:
                 "label": jnp.argmax(s, axis=1).astype(jnp.int32)}
 
 
+# a pytree, so a jitted program takes the model's arrays as an argument
+# instead of baking them in as constants: one compile serves any weights
+jax.tree_util.register_dataclass(
+    ClassifyPlan, data_fields=["centroids", "w", "b", "gbdt"],
+    meta_fields=["n_classes", "head", "vc", "mode", "ladder", "normalize"])
+
+
 def build_plan(model, config: PipelineConfig | None = None) -> ClassifyPlan:
     """Bind a trained model to a ClassifyPlan using the config's
     classifier knobs (classify_mode / classify_ladder / vc).

@@ -39,6 +39,11 @@ class GbdtModel:
     n_classes: int
 
 
+jax.tree_util.register_dataclass(
+    GbdtModel, data_fields=["feat", "thr", "leaf", "base"],
+    meta_fields=["n_classes"])
+
+
 def _level_split(x: Array, r: Array, pid: Array, n_leaves: int,
                  thresholds: Array):
     """Best oblivious split for one level: maximize sum over children of

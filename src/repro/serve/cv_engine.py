@@ -144,9 +144,11 @@ class CvEngine:
         self.dispatcher = dispatcher
         self.captured: list = []     # (bucket, canonical batch) when capturing
         self._submits = 0            # index of the last submit, for its span
+        self._local_fns: dict = {}   # rung -> jit(_batch_body at that rung)
         self.stats = {"served": 0, "errors": 0, "degraded_batches": 0,
                       "retries": 0, "deadline_missed": 0, "sanitized": 0,
-                      "sharded_batches": 0, "shard_failures": 0}
+                      "sharded_batches": 0, "shard_failures": 0,
+                      "batch_traces": 0}
 
     @property
     def signature(self) -> str:
@@ -221,24 +223,44 @@ class CvEngine:
 
     # -- ladder execution ----------------------------------------------------
 
-    def _batch_fn(self, x, rung: str):
+    def _batch_body(self, x, rung: str, plan=None):
         """Traceable per-rung batch computation: (B, H, W[, C]) jax array
         -> dict of batch-leading jax arrays.  No host sync, no timing —
         it must trace under `shard_map`, so both the local ladder
-        (`_run_batch`) and the sharded dispatcher run through it; the
+        (`_batch_fn`) and the sharded dispatcher run through it; the
         classify composition matches `pipeline.predict` numerically.
+        `plan` stands in for ``self.plan`` where the caller passes the
+        classifier tail as a traced argument.
 
         The stencil rung maps onto the classifier tail's two rungs: the
         jnp floor ("ref") classifies through the staged oracle, every
         fused stencil rung classifies through the fused tail."""
+        plan = self.plan if plan is None else plan
         feats = pipeline.extract_features(
             x, self.config.replace(mode=rung), validate=False)
-        if self.plan is not None:
+        if plan is not None:
             cmode = "ref" if rung == "ref" else "fused"
-            hists = self.plan.histograms(feats["desc"], feats["valid"],
-                                         mode=cmode)
-            return {"pred": self.plan.classify(hists, mode=cmode)}
+            hists = plan.histograms(feats["desc"], feats["valid"],
+                                    mode=cmode)
+            return {"pred": plan.classify(hists, mode=cmode)}
         return {"desc": feats["desc"], "valid": feats["valid"]}
+
+    def _batch_fn(self, x, rung: str):
+        """One batch of the local ladder through the rung's compiled
+        program: `jax.jit` of `_batch_body` at that rung, built on first
+        use, which keeps one executable per (batch shape, dtype).  The
+        classifier tail goes in as an argument, so its arrays are not
+        compiled in.  Lowering errors fire while a shape's first call
+        traces, and a trace that raised caches nothing, so the next call
+        of that shape traces again.  ``stats["batch_traces"]`` counts
+        the traces, not the calls."""
+        fn = self._local_fns.get(rung)
+        if fn is None:
+            def traced(xs, plan):
+                self.stats["batch_traces"] += 1
+                return self._batch_body(xs, rung, plan)
+            fn = self._local_fns[rung] = jax.jit(traced)
+        return fn(x, self.plan)
 
     def _run_batch(self, batch: np.ndarray, rung: str):
         """One canonical batch through the pipeline at one explicit rung."""
@@ -312,6 +334,8 @@ class CvEngine:
              deadline_s: float | None = 5.0, seed: int = 0) -> dict | None:
         """Warm the plan table for one bucket's octave chain; a measurement
         timeout degrades to heuristic routing instead of raising."""
+        # programs traced at an explicit rung never read the plan table,
+        # so the engine's compiled programs outlive a re-measurement
         h, w = bucket
         gen = np.random.default_rng(seed)
         img = jnp.asarray(gen.random((h, w), dtype=np.float32))
@@ -352,7 +376,8 @@ class CvEngine:
         device ops: ``serve.submit`` (the whole call, keyword ``submit``:
         its index) holds ``serve.admit`` (admission, bucketing, padding),
         then per group batch ``serve.stack``, ``serve.h2d`` (copy to the
-        device), ``serve.dispatch`` (trace or cache lookup, enqueue),
+        device), ``serve.dispatch`` (the rung's compiled program: traced
+        and compiled on a shape's first call, else looked up; enqueue),
         ``serve.wait`` (the device computing), ``serve.d2h`` (the fetch)
         and ``serve.assemble`` (the Responses); the sharded path puts
         ``serve.fanout`` around its transfers and dispatch."""
@@ -449,7 +474,7 @@ class CvEngine:
         domains, per-request Responses carrying shard/device identity."""
         try:
             report = self.dispatcher.dispatch(
-                batch, self._batch_fn, signature=self.signature,
+                batch, self._batch_body, signature=self.signature,
                 bucket=tuple(bucket), mode=self.ladder[0])
         except ValueError:
             raise                    # caller bug, not a serving fault

@@ -348,7 +348,7 @@ class ShardDispatcher:
         batch: (B, H, W[, C]) canonical np/jax batch (already admitted,
             bucket-padded — the engine's groups).
         fn(x, rung) -> dict of batch-leading jax arrays: the traceable
-            per-rung batch computation (`CvEngine._batch_fn`).  It must
+            per-rung batch computation (`CvEngine._batch_body`).  It must
             not install its own ladder — the dispatcher owns degradation.
         signature/bucket: the workload identity half of the breaker key.
         mode: explicit start rung (default: the ladder's first rung); the

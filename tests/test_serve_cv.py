@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import run_subprocess
 from repro.core import faultinject
 from repro.cv import PipelineConfig, features, pipeline
 from repro.kernels import ref, stencil
@@ -111,6 +112,92 @@ def test_submit_spans_each_phase_once_per_group(tmp_path):
         "serve.submit": 1, "serve.admit": 1, **{n: 2 for n in per_batch}}
     (_, lo, hi), = [s for s in spans if s[0] == "serve.submit"]
     assert all(lo <= s and e <= hi for _, s, e in spans)
+
+
+def test_repeat_submit_reuses_compiled_program(tmp_path):
+    """A second submit of the same bucket and batch size runs the program
+    the first one compiled: no new trace, no compile request to the
+    persistent cache."""
+    out = run_subprocess(f"""
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = {str(tmp_path)!r}
+        import numpy as np
+        from repro.core import jaxcache
+        from repro.serve.cv_engine import CvEngine
+        jaxcache.enable()
+        gen = np.random.default_rng(4)
+        work = [gen.integers(0, 256, (30, 28, 3), dtype=np.uint8)
+                for _ in range(3)]
+        eng = CvEngine(buckets=((32, 32),), max_kp=8)
+        assert all(r.ok for r in eng.extract(work))
+        t0, c0 = eng.stats["batch_traces"], jaxcache.counts()["requests"]
+        assert all(r.ok for r in eng.extract(work[::-1]))
+        print("REUSE", t0, eng.stats["batch_traces"] - t0,
+              jaxcache.counts()["requests"] - c0)
+    """, devices=1, timeout=300)
+    assert "REUSE 1 0 0" in out
+
+
+def test_new_batch_size_adds_one_trace():
+    work = _rgb_u8(5, seed=6)
+    eng = CvEngine(buckets=((32, 32),), max_kp=8)
+    assert all(r.ok for r in eng.extract(work[:2]))
+    assert eng.stats["batch_traces"] == 1
+    assert all(r.ok for r in eng.extract(work[2:]))      # batch of 3
+    assert eng.stats["batch_traces"] == 2
+    assert all(r.ok for r in eng.extract(work[:3]))      # 3 again: cached
+    assert eng.stats["batch_traces"] == 2
+
+
+def test_clear_caches_retraces_held_programs():
+    """`jax.clear_caches()`, which the fault tests use to make a shape
+    trace again, also reaches the programs the engine holds."""
+    work = _rgb_u8(2, seed=8)
+    eng = CvEngine(buckets=((32, 32),), max_kp=8)
+    eng.extract(work)
+    eng.extract(work)
+    assert eng.stats["batch_traces"] == 1
+    jax.clear_caches()
+    assert all(r.ok for r in eng.extract(work))
+    assert eng.stats["batch_traces"] == 2
+
+
+def test_classify_program_takes_the_model_as_an_argument():
+    """Models that differ only in their weights lower to one program: the
+    tail's arrays are arguments, not constants, so a new model reuses
+    what the persistent compile cache holds."""
+    work = _rgb_u8(2, lo=32, hi=32, seed=10)
+    engines = []
+    for seed in (0, 1):
+        kc, kw = jax.random.split(jax.random.PRNGKey(seed))
+        model = pipeline.BowSvmModel(
+            centroids=jax.random.normal(kc, (16, 128)),
+            svm={"w": jax.random.normal(kw, (3, 16)), "b": jnp.zeros(3)},
+            n_classes=3)
+        eng = CvEngine(model, buckets=((32, 32),), max_kp=8)
+        assert all(r.ok and 0 <= r.pred < 3 for r in eng.classify(work))
+        engines.append(eng)
+    x = jnp.asarray(np.stack(work))
+    texts = [e._local_fns["streaming"].lower(x, e.plan).as_text()
+             for e in engines]
+    assert texts[0] == texts[1]
+
+
+def test_failed_trace_is_not_cached():
+    """A lowering fault fires while the first rung traces; the batch
+    degrades, and the next submit of the same shape traces that rung again
+    and is served on it."""
+    work = _gray_f32(3, seed=5)
+    eng = CvEngine(buckets=((48, 48),), max_batch=8, max_kp=8,
+                   max_retries=0)
+    jax.clear_caches()
+    with faultinject.inject("lowering_error:count=1"):
+        first = eng.extract(work)
+        second = eng.extract(work)
+    assert all(r.ok and r.degraded and r.plan == "tiled2d" for r in first)
+    assert all(r.ok and not r.degraded and r.plan == "streaming"
+               for r in second)
+    # failed streaming trace + tiled2d, then streaming traced again
+    assert eng.stats["batch_traces"] == 3
 
 
 def test_engine_rejects_malformed_frames():
