@@ -2,9 +2,11 @@
 
 The same generative classes as the program's `ImageStream` (a tilted
 sine grating, a signed Gaussian blob, pixel noise, per-channel gains;
-u8 C3), drawn with `jax.random` so a pool of 1080p frames costs one
+u8), drawn with `jax.random` so a pool of 1080p frames costs one
 compiled call per chunk instead of seconds of host work per frame.  The
-same seed gives the same frames.
+same seed gives the same frames.  Channel c's gain does not depend on
+how many channels a frame has, so a C1 frame is channel 0 of the C3
+frame of the same seed.
 """
 from __future__ import annotations
 
@@ -28,7 +30,7 @@ def seed_key(seed: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _chunk_fn(h: int, w: int, n: int):
+def _chunk_fn(h: int, w: int, n: int, channels: int):
     import jax
     import jax.numpy as jnp
 
@@ -53,19 +55,20 @@ def _chunk_fn(h: int, w: int, n: int):
         sign = (c % 2).astype(jnp.float32) * 2 - 1
         img = 0.6 * wave + 0.8 * blob * sign \
             + 0.15 * jax.random.normal(kn, (h, w))
-        gains = 0.5 + 0.5 * jnp.sin(cf + jnp.arange(3, dtype=jnp.float32))
+        gains = 0.5 + 0.5 * jnp.sin(cf + jnp.arange(channels,
+                                                  dtype=jnp.float32))
         px = (img[..., None] * gains * 0.5 + 0.5) * 255
         return jnp.clip(px, 0, 255).astype(jnp.uint8)
 
     return jax.jit(lambda key: jax.vmap(one)(jax.random.split(key, n)))
 
 
-def pool(seed: int, n: int, h: int, w: int) -> np.ndarray:
-    """(n, h, w, 3) u8 host frames from `seed`, made CHUNK at a time on
-    the default device."""
+def pool(seed: int, n: int, h: int, w: int, channels: int = 3) -> np.ndarray:
+    """(n, h, w, channels) u8 host frames from `seed`, made CHUNK at a
+    time on the default device."""
     import jax
     key = seed_key(seed)
-    fn = _chunk_fn(h, w, CHUNK)
+    fn = _chunk_fn(h, w, CHUNK, channels)
     chunks = [jax.device_get(fn(jax.random.fold_in(key, i)))
               for i in range(-(-n // CHUNK))]
     return np.concatenate(chunks)[:n]

@@ -5,7 +5,7 @@ the window served, and the metrics.
 The window is a closed loop with one client: the next submit leaves when
 the previous one has returned.  Every answer served in the window is kept
 and, once the window has closed and the device memory peak is read, held
-to the plain reference (`benchlib.reference`) for the same frame.
+to the plain reference of the cell's task for the same frame.
 """
 from __future__ import annotations
 
@@ -18,9 +18,10 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from benchlib import frames, reference, stats
+from benchlib import frames, stats
 from benchlib import trace as trace_mod
 from benchlib.peaks import peaks
+from benchlib.spec import SpecError
 
 WARM_SUBMITS = 2        # the first compiles (or loads), the second is steady
 
@@ -50,6 +51,7 @@ class Context:
     images: int
     devices: list           # device indices the cell uses
     device_kind: str
+    task: object            # the cell's task module
     trace: object = None    # benchlib.trace.Trace of the window, when traced
 
     def peak(self, key: str) -> float:
@@ -135,7 +137,11 @@ class Setup:
         self.times["device_init_s"] = t - t_start
         cfg, tr = cell.config, cell.traffic
         f = cfg["frame"]
-        self.pool = frames.pool(seed, tr["pool"], f["height"], f["width"])
+        if f["dtype"] != "uint8":
+            raise SpecError(f"{cfg['name']}: frames of {f['dtype']!r}; the "
+                            "frame pool makes uint8 only")
+        self.pool = frames.pool(seed, tr["pool"], f["height"], f["width"],
+                                f["channels"])
         self.head = cell.task.head(cfg, seed)
         t2 = time.perf_counter()
         self.times["model_build_s"] = t2 - t
@@ -209,10 +215,10 @@ def memory_peak(devs) -> int | None:
 
 
 def references(cell, pool, head, served, *, dtype: str = "float32") -> dict:
-    """The plain reference, computed in `dtype`, of each pool frame in
-    `served`, by pool index."""
-    return dict(zip(served, reference.reference(cell.config, pool[served],
-                                                head, dtype=dtype)))
+    """The task's plain reference, computed in `dtype`, of each pool frame
+    in `served`, by pool index."""
+    return dict(zip(served, cell.task.reference(cell.config, pool[served],
+                                                head, dtype)))
 
 
 def judge(cell, answers, refs) -> dict:
@@ -259,7 +265,7 @@ def run(cell, *, seed: int, seconds: float, trace: bool,
               "memory_peak_bytes": memory_peak(st.devices)}
     ctx = Context(config=cell.config, submits=subs, images=images,
                   devices=[d.id for d in st.devices],
-                  device_kind=dev0.device_kind, trace=tr)
+                  device_kind=dev0.device_kind, task=cell.task, trace=tr)
     metrics, breakdown, notes = {}, None, []
     if trace:
         device["busy_s"] = trace_mod.busy_s(tr, ctx.devices)

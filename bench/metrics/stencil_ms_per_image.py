@@ -1,5 +1,5 @@
-"""Device time of the stencil kernels (the fused preprocess and octave
-chains, Pallas ops named `_chain_planes`) per served frame."""
+"""Device time of the stencil kernels (the fused chains of the cell's
+task, Pallas ops named `_chain_planes`) per served frame."""
 from benchlib import layers
 
 

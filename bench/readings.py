@@ -5,13 +5,13 @@
 
 For each seed, in one process on the chip: the cell's set-up, then every
 frame of its pool served once through the cell's own engine at the
-cell's batch, and each compared number read against the float32 plain
-reference: for what the program served (the lower reading) and for the
-control, the reference itself computed in bfloat16 (the upper reading).
-A classify cell adds two readings of its tail alone, on the float32
-reference's descriptors: the tail in bfloat16, and the tail with half of
-each frame's descriptors left out of its histogram.  One JSON line per
-seed on standard output; a summary last.
+cell's batch, and each compared number read against the task's float32
+plain reference: for what the program served (the lower reading) and for
+the control, the reference itself computed in bfloat16 (the upper
+reading).  The task may add readings of its own (`readings`; the
+classify task reads its tail alone in bfloat16 and with half of each
+frame's descriptors left out).  One JSON line per seed on standard
+output; a summary last.
 """
 import argparse
 import json
@@ -23,59 +23,14 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 
 
-def control_answers(refs: dict) -> list:
+def control_answers(task, refs: dict) -> list:
     """The reference computed in the control's precision, as answers."""
-    import numpy as np
-    ans = []
-    for i, o in sorted(refs.items()):
-        if "scores" in o:
-            ans.append((i, {"label": int(np.argmax(o["scores"]))}))
-        else:
-            ans.append((i, {"desc": o["desc"], "valid": o["valid"]}))
-    return ans
+    return [(i, task.control_answer(o)) for i, o in sorted(refs.items())]
 
 
-def tail_answers(refs: dict, head, *, dtype: str, keep_half: bool) -> list:
-    """Labels from the reference's float32 descriptors through the
-    reference tail alone, in `dtype`, or with the second half of each
-    frame's keypoints left out of its histogram."""
-    import jax.numpy as jnp
-    import numpy as np
-    from benchlib import reference
-    ans = []
-    for i, o in sorted(refs.items()):
-        valid = np.asarray(o["valid"], bool)
-        if keep_half:
-            valid = valid & (np.cumsum(valid) <= valid.sum() // 2)
-        s = reference.bow_scores(jnp.asarray(o["desc"]), jnp.asarray(valid),
-                                 *head, jnp.dtype(dtype))
-        ans.append((i, {"label": int(np.argmax(np.asarray(s, np.float32)))}))
-    return ans
-
-
-def misses(answers, refs: dict, atol: float) -> list:
-    """Where a served descriptor has no reference counterpart within the
-    tolerance: (pool frame, keypoint, its nearest reference distance),
-    to tell an orientation bin crossing (a few hundredths) from another
-    keypoint in the top-k (tenths)."""
-    import numpy as np
-    out, seen = [], set()
-    for i, a in answers:
-        if i in seen:
-            continue
-        seen.add(i)
-        r = refs[i]
-        got, want = a["desc"][a["valid"]], r["desc"][r["valid"]]
-        near = np.array([np.abs(g[None] - want).max(axis=-1).min()
-                         for g in got])
-        for k in np.nonzero(near > atol)[0]:
-            out.append([int(i), int(k), float(near[k])])
-    return out
-
-
-def read_seed(cell, seed: int) -> dict:
+def read_seed(cell, seed: int, *, platform: str = "tpu") -> dict:
     from benchlib import harness
-    st = harness.Setup(cell, seed)
+    st = harness.Setup(cell, seed, platform=platform)
     tr = cell.traffic
     answers, bad = [], 0
     for k in range(-(-tr["pool"] // tr["batch"])):
@@ -93,18 +48,28 @@ def read_seed(cell, seed: int) -> dict:
         return {k: v["value"] for k, v in
                 harness.judge(cell, ans, refs).items()}
 
-    kp = [int(r["valid"].sum()) for r in refs.values()]
     row = {"seed": seed, "frames": len(answers), "failed": bad,
-           "kp_min": min(kp), "kp_max": max(kp),
-           "program": values(answers), "control": values(control_answers(low))}
-    if head is not None:
-        row["tail_bf16"] = values(tail_answers(refs, head, dtype="bfloat16",
-                                               keep_half=False))
-        row["tail_half"] = values(tail_answers(refs, head, dtype="float32",
-                                               keep_half=True))
-    else:
-        row["misses"] = misses(answers, refs, cell.task.DESC_ATOL)
+           "program": values(answers),
+           "control": values(control_answers(cell.task, low))}
+    if hasattr(cell.task, "readings"):
+        row.update(cell.task.readings(cell.config, answers, refs, head))
     return row
+
+
+def summary(workload: str, rows: list) -> dict:
+    """Over the seeds: the largest program reading (the lower end of a
+    limit), the smallest control and task stand-in readings (the upper
+    end), and the largest of each `*_max` reading."""
+    names = rows[0]["program"].keys()
+    out = {"workload": workload, "seeds": len(rows)}
+    for k in rows[0]:
+        if k.endswith("_max"):
+            out[k] = max(r[k] for r in rows)
+    out["lower"] = {n: max(r["program"][n] for r in rows) for n in names}
+    for k, v in rows[0].items():
+        if isinstance(v, dict) and k != "program":
+            out[k] = {n: min(r[k][n] for r in rows) for n in names}
+    return out
 
 
 def main(argv=None) -> int:
@@ -123,14 +88,7 @@ def main(argv=None) -> int:
         row["seconds"] = time.perf_counter() - t
         rows.append(row)
         print(json.dumps(row), flush=True)
-    names = rows[0]["program"].keys()
-    summary = {"workload": a.workload, "seeds": len(rows),
-               "kp_max": max(r["kp_max"] for r in rows),
-               "lower": {n: max(r["program"][n] for r in rows) for n in names}}
-    for side in ("control", "tail_bf16", "tail_half"):
-        if side in rows[0]:
-            summary[side] = {n: min(r[side][n] for r in rows) for n in names}
-    print(json.dumps(summary), flush=True)
+    print(json.dumps(summary(a.workload, rows)), flush=True)
     return 0
 
 

@@ -8,7 +8,11 @@ held to the plain reference's scores for the same frame.
 """
 from __future__ import annotations
 
-from benchlib import compare, frames
+import numpy as np
+
+from benchlib import compare, frames, sift
+from benchlib import reference as plain
+from benchlib.sift import stencil_chains  # noqa: F401  (task interface)
 
 PLAN = "streaming"
 HEAD_STREAM = 0x5EED     # key stream of the head, apart from the frames'
@@ -65,3 +69,44 @@ def numbers(cfg: dict, answers: list, refs: dict) -> dict:
     for k, a in answers:
         worst = max(worst, compare.label_gap(refs[k]["scores"], a["label"]))
     return {"label_gap": worst}
+
+
+def reference(cfg: dict, frames_, head_arrays, dtype: str) -> list:
+    """The plain reference's {"desc", "valid", "scores"} of each frame."""
+    return plain.reference(cfg, frames_, head_arrays, dtype=dtype)
+
+
+def control_answer(ref: dict) -> dict:
+    return {"label": int(np.argmax(ref["scores"]))}
+
+
+def tail_answers(refs: dict, head_arrays, *, dtype: str,
+                 keep_half: bool) -> list:
+    """Labels from the reference's float32 descriptors through the
+    reference tail alone, in `dtype`, or with the second half of each
+    frame's keypoints left out of its histogram."""
+    import jax.numpy as jnp
+    ans = []
+    for i, o in sorted(refs.items()):
+        valid = np.asarray(o["valid"], bool)
+        if keep_half:
+            valid = valid & (np.cumsum(valid) <= valid.sum() // 2)
+        s = plain.bow_scores(jnp.asarray(o["desc"]), jnp.asarray(valid),
+                             *head_arrays, jnp.dtype(dtype))
+        ans.append((i, {"label": int(np.argmax(np.asarray(s, np.float32)))}))
+    return ans
+
+
+def readings(cfg: dict, answers: list, refs: dict, head_arrays) -> dict:
+    """The reference's keypoint counts, and two readings of the tail
+    alone on the float32 reference's descriptors: the tail in bfloat16,
+    and the tail with half of each frame's descriptors left out of its
+    histogram."""
+    return dict(
+        sift.keypoint_range(refs),
+        tail_bf16=numbers(cfg, tail_answers(refs, head_arrays,
+                                            dtype="bfloat16",
+                                            keep_half=False), refs),
+        tail_half=numbers(cfg, tail_answers(refs, head_arrays,
+                                            dtype="float32",
+                                            keep_half=True), refs))

@@ -8,7 +8,9 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchlib import compare
+from benchlib import compare, sift
+from benchlib import reference as plain
+from benchlib.sift import stencil_chains  # noqa: F401  (task interface)
 
 PLAN = "streaming"
 # a descriptor element that differs by more than this has not matched
@@ -55,3 +57,37 @@ def numbers(cfg: dict, answers: list, refs: dict) -> dict:
         worst = max(worst, compare.unmatched_share(
             a["desc"], a["valid"], r["desc"], r["valid"], DESC_ATOL))
     return {"unmatched_kp": worst}
+
+
+def reference(cfg: dict, frames, head, dtype: str) -> list:
+    """The plain reference's {"desc", "valid"} of each frame."""
+    return plain.reference(cfg, frames, head, dtype=dtype)
+
+
+def control_answer(ref: dict) -> dict:
+    return {"desc": ref["desc"], "valid": ref["valid"]}
+
+
+def misses(answers, refs: dict) -> list:
+    """Where a served descriptor has no reference counterpart within
+    DESC_ATOL: (pool frame, keypoint, its nearest reference distance),
+    to tell an orientation bin crossing (a few hundredths) from another
+    keypoint in the top-k (tenths)."""
+    out, seen = [], set()
+    for i, a in answers:
+        if i in seen:
+            continue
+        seen.add(i)
+        r = refs[i]
+        got, want = a["desc"][a["valid"]], r["desc"][r["valid"]]
+        near = np.array([np.abs(g[None] - want).max(axis=-1).min()
+                         for g in got])
+        for k in np.nonzero(near > DESC_ATOL)[0]:
+            out.append([int(i), int(k), float(near[k])])
+    return out
+
+
+def readings(cfg: dict, answers: list, refs: dict, head) -> dict:
+    """The reference's keypoint counts and every served descriptor that
+    missed."""
+    return dict(sift.keypoint_range(refs), misses=misses(answers, refs))

@@ -47,7 +47,8 @@ SUBMITS = [
 def _ctx(tr, cell="sift1080p.b16"):
     c = spec.Cell(cell, tinycells.ROOT)
     return harness.Context(config=c.config, submits=[], images=2,
-                           devices=[0], device_kind="TPU v5 lite", trace=tr)
+                           devices=[0], device_kind="TPU v5 lite",
+                           task=c.task, trace=tr)
 
 
 def _readers(cell):
@@ -129,8 +130,7 @@ import tinycells
 from benchlib import harness
 name = sys.argv[1]
 four = name.endswith("x4")
-cell = tinycells.cell(name, batch=8 if four else 2, pool=8,
-                      workload=tinycells.FOUR_CHIPS if four else None)
+cell = tinycells.cell(name, batch=8 if four else 2, pool=8)
 r = harness.run(cell, seed={seed}, seconds=0.5, trace=True,
                 platform="cpu", log=print)
 print("CORRECT", r.correct)
@@ -155,10 +155,8 @@ def test_traced_run_reads_every_new_metric(name, devices):
     assert "CORRECT True" in p.stdout, p.stdout[-2000:]
     got = {ln.split()[1]: float(ln.split()[2])
            for ln in p.stdout.splitlines() if ln.startswith("METRIC")}
-    listed = {m["name"] for m, _ in spec.Cell(name, tinycells.ROOT,
-                                              workload=tinycells.FOUR_CHIPS
-                                              if devices == 4 else None
-                                              ).per_layer()}
+    listed = {m["name"]
+              for m, _ in spec.Cell(name, tinycells.ROOT).per_layer()}
     assert set(NEW) & listed <= set(got), got
     win = re.search(r"window: (\d+) submits.*compile requests (\d+)",
                     p.stdout)

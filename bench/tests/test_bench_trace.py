@@ -95,11 +95,12 @@ def test_no_window_span_is_an_error():
 
 def _ctx(tr, images=4):
     from benchlib import harness, spec
-    cfg = spec.load_json(f"{tinycells.BENCH}/configs/sift1080p.json")
+    c = spec.Cell("sift1080p.b16", tinycells.ROOT)
     subs = [harness.Submit(0.0, 0.5, [0, 1], 0.4, 0),
             harness.Submit(0.5, 0.8, [2, 3], 0.1, 0)]
-    return harness.Context(config=cfg, submits=subs, images=images,
-                           devices=[0], device_kind="TPU v5 lite", trace=tr)
+    return harness.Context(config=c.config, submits=subs, images=images,
+                           devices=[0], device_kind="TPU v5 lite",
+                           task=c.task, trace=tr)
 
 
 def _readers():

@@ -14,17 +14,16 @@ BENCHMARK = spec.benchmark(tinycells.ROOT)
 def test_stencil_bytes_by_hand():
     # preprocess: 3 f32 planes in + 3 out; octave: 1 in + 7 scales out
     plane = 1080 * 1920 * 4
-    cfg = spec.load_json(os.path.join(tinycells.BENCH, "configs",
-                                      "sift1080p.json"))
-    assert stencil_bytes.frame_bytes(cfg) == (6 + 8) * plane == 116121600
+    c = spec.Cell("sift1080p.b16", tinycells.ROOT)
+    assert stencil_bytes.frame_bytes(c.config, c.task) == (6 + 8) * plane \
+        == 116121600
     assert stencil_bytes.chain_bytes(2, 3, 1, 1, "bfloat16") == 24
 
 
 def test_stencil_bytes_refuses_a_pyramid():
-    cfg = spec.load_json(os.path.join(tinycells.BENCH, "configs",
-                                      "sift1080p.json"))
+    c = spec.Cell("sift1080p.b16", tinycells.ROOT)
     with pytest.raises(NotImplementedError):
-        stencil_bytes.frame_bytes(dict(cfg, n_octaves=4))
+        stencil_bytes.frame_bytes(dict(c.config, n_octaves=4), c.task)
 
 
 def test_p95_over_every_submit():
@@ -52,7 +51,8 @@ def test_cells_find_their_files_by_name(w):
     c = spec.Cell(w, tinycells.ROOT)
     assert c.config["name"] == c.workload["config"]
     assert c.traffic["batch"] >= 1 and c.traffic["pool"] >= 2 * c.traffic["batch"]
-    for fn in ("head", "engine", "submit", "answer", "numbers"):
+    for fn in ("head", "engine", "submit", "answer", "numbers", "reference",
+               "control_answer", "stencil_chains"):
         assert callable(getattr(c.task, fn))
     assert c.config["limits"], "no limit for correct"
     names = {m["name"] for m, _ in c.per_layer()}

@@ -16,16 +16,10 @@ for p in (BENCH, os.path.join(ROOT, "src")):
 H, W, MAX_KP = 48, 128, 256
 
 
-def cell(name: str, *, batch: int = 2, pool: int = 4, workload=None):
+def cell(name: str, *, batch: int = 2, pool: int = 4):
     from benchlib import spec
-    c = spec.Cell(name, ROOT, workload=workload)
+    c = spec.Cell(name, ROOT)
     cfg = dict(c.config, frame=dict(c.config["frame"], height=H, width=W),
                max_kp=MAX_KP)
-    return spec.Cell(name, ROOT, config=cfg, workload=workload,
+    return spec.Cell(name, ROOT, config=cfg,
                      traffic=dict(c.traffic, batch=batch, pool=pool))
-
-
-# the four-chip cell of `bench/traffic/b64x4.json`, not yet proven on the
-# chip and so not in BENCHMARK.json
-FOUR_CHIPS = {"name": "sift1080p.b64x4", "config": "sift1080p",
-              "traffic": "b64x4", "chips": 4}
