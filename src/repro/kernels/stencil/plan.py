@@ -63,13 +63,17 @@ def round_sublanes(rows: int, dtype) -> int:
 
 @dataclass(frozen=True)
 class WorkingSet:
-    """Bytes used per grid step as a function of the config."""
+    """Bytes used per grid step as a function of the config: `fn` counts
+    what the double buffering doubles, `held` (optional) what the kernel
+    body holds once, whatever the buffering."""
     fn: Callable[["VectorConfig"], int]
     double_buffer: bool = True       # Pallas pipelines HBM->VMEM copies
+    held: Callable[["VectorConfig"], int] | None = None
 
     def bytes(self, vc) -> int:
         b = self.fn(vc)
-        return 2 * b if self.double_buffer else b
+        b = 2 * b if self.double_buffer else b
+        return b + (self.held(vc) if self.held is not None else 0)
 
 
 def pick_lmul(ws: WorkingSet, *, base=None):
@@ -220,6 +224,13 @@ def chain_working_set(stages, width: int, in_dtype=jnp.uint8, *,
     blocks.  ``width`` is the per-grid-step *tile* width (the full image
     width untiled; `tile_w` under the tiled2d plan).  `stages` is
     duck-typed (``.op``/``.halo``; optional ``.stride``/``.tap``).
+
+    The input window is charged at the rows its DMA block really has
+    (rounded up to the sublane tile).  A filter2d stage also holds f32
+    out-bands for its kernel rows (`held`, not doubled).  Compiled for a
+    v5e, u8 filter2D needs, beyond the doubled part, about kh - 3 such
+    bands up to 11 kernel rows and kh + 4 at 13 and 15: one band per row
+    is charged up to 11 rows, one and a half from 13 on.
     """
     plan = resolve_chain(stages)
     ph_in, pw_in = chain_accumulated_halo(stages)
@@ -230,12 +241,15 @@ def chain_working_set(stages, width: int, in_dtype=jnp.uint8, *,
     w_bytes = sum(int(w.size) * jnp.dtype(w.dtype).itemsize
                   for s in stages for w in getattr(s, "weights", ()))
 
-    def fn(vc) -> int:
+    def count(vc) -> tuple[int, int]:
+        """(bytes the double buffering doubles, bytes held once)."""
         rows = vc.rows(in_dtype)
         iface = chain_iface(plan, rows)
         sp = chain_stream_plan(plan, iface) if streaming else None
         wp = _round_lane(vc, width, pw_in)
-        total = iface[0][2] * wp * itemsize + w_bytes    # input window DMA
+        r_block = round_sublanes(iface[0][2], in_dtype)
+        total = r_block * wp * itemsize + w_bytes        # input window DMA
+        held = 0
         num, den = 1, 1                # net width scale so far (down / up)
         sizes = [itemsize]                 # live-band element sizes (bytes):
         for k, (op, mode, halo, stride, up, n_in, n_out, tap) in enumerate(plan):
@@ -276,13 +290,20 @@ def chain_working_set(stages, width: int, in_dtype=jnp.uint8, *,
             wp_out = max(vc.lane, wp_s * (up[1] if mode == "map" else 1))
             if widen:
                 total += n_part * out_r * wp_out * 4
+            if op == "filter2d":
+                # each kernel row is read at its own sublane offset, and
+                # Mosaic holds every such row slice as an f32 out-band;
+                # from 13 rows on, half as much again (v5e compiles of
+                # 13x13 and 15x15 kernels)
+                kh = 2 * halo[0] + 1
+                held += kh * n_part * out_r * wp_out * (4 if kh <= 11 else 6)
             total += sum(out_r * wp_out * sz for sz in sizes)
             if mode == "map":
                 num *= stride[1]
                 den *= up[1]
         total += rows * wp * itemsize                    # store band(s)
-        return total
-    return WorkingSet(fn)
+        return total, held
+    return WorkingSet(lambda vc: count(vc)[0], held=lambda vc: count(vc)[1])
 
 
 def pick_chain_lmul(stages, width: int, in_dtype=jnp.uint8, *,

@@ -80,6 +80,7 @@ class Response:
     desc: np.ndarray | None = None   # extract task: (max_kp, 128) descriptors
     valid: np.ndarray | None = None
     pred: int | None = None          # classify task
+    image: np.ndarray | None = None  # filter task: the frame's chain output
     bucket: tuple | None = None
     plan: str | None = None          # the rung that produced the answer
     retries: int = 0
@@ -98,12 +99,14 @@ class CvEngine:
     task="extract" serves descriptor sets (no model needed);
     task="classify" serves class predictions through the
     `cv.classify.ClassifyPlan` tail (pass a trained `BowSvmModel` /
-    `BowGbdtModel`).  Pipeline knobs come in via ``config=``
+    `BowGbdtModel`); task="filter" serves each frame's output of one
+    `kernels.stencil` chain (pass its stages as ``chain=``), in the
+    frame's own dtype and shape.  Pipeline knobs come in via ``config=``
     (`cv.config.PipelineConfig`); the old `n_octaves=`/`preprocess=`
     kwargs survive as deprecation shims."""
 
     def __init__(self, model=None, config: PipelineConfig | None = None, *,
-                 buckets=DEFAULT_BUCKETS,
+                 chain=None, buckets=DEFAULT_BUCKETS,
                  max_batch: int = 64, ladder=DEFAULT_LADDER,
                  max_retries: int = 1, backoff_s: float = 0.01,
                  bad_input: str = "sanitize", max_kp=_UNSET,
@@ -119,12 +122,25 @@ class CvEngine:
         for rung in ladder:
             if rung not in stencil.MODES:
                 raise ValueError(f"unknown ladder rung {rung!r}")
+        if model is not None and chain is not None:
+            raise ValueError("pass model= (classify) OR chain= (filter), "
+                             "not both")
+        if chain is not None:
+            chain = tuple(chain)
+            if not chain or stencil.resolve_chain(chain)[-1][6] != 1:
+                raise ValueError("chain= must be stages that end in one "
+                                 "output band")
         cfg = resolve_config(config, where="CvEngine", max_kp=max_kp,
                              n_octaves=n_octaves, preprocess=preprocess)
         self.model = model
+        self.chain = chain
         self.config = cfg
         self.plan = (classify.build_plan(model, cfg)
                      if model is not None else None)
+        # the batch program's traced argument: the classifier tail, or the
+        # chain's weights, so that neither is compiled in as a constant
+        self._program_arg = (stencil.ir.flat_weights(chain)
+                             if chain is not None else self.plan)
         self.buckets = tuple(sorted(tuple(b) for b in buckets))
         self.max_batch = int(max_batch)
         self.ladder = ladder
@@ -151,11 +167,19 @@ class CvEngine:
                       "batch_traces": 0}
 
     @property
+    def task(self) -> str:
+        if self.chain is not None:
+            return "filter"
+        return "classify" if self.model is not None else "extract"
+
+    @property
     def signature(self) -> str:
         """Workload identity half of the circuit-breaker key: one string
-        per (task, pipeline knobs) — bucket and rung complete the key."""
-        task = "classify" if self.model is not None else "extract"
-        return (f"cv:{task}:kp{self.max_kp}:oct{self.n_octaves}"
+        per (task, pipeline knobs or chain) — bucket and rung complete the
+        key."""
+        if self.chain is not None:
+            return f"cv:filter:{stencil.ir.spec_of(self.chain)}"
+        return (f"cv:{self.task}:kp{self.max_kp}:oct{self.n_octaves}"
                 f":pre{int(self.preprocess)}")
 
     # -- admission -----------------------------------------------------------
@@ -223,19 +247,28 @@ class CvEngine:
 
     # -- ladder execution ----------------------------------------------------
 
-    def _batch_body(self, x, rung: str, plan=None):
+    def _batch_body(self, x, rung: str, arg=None):
         """Traceable per-rung batch computation: (B, H, W[, C]) jax array
         -> dict of batch-leading jax arrays.  No host sync, no timing —
         it must trace under `shard_map`, so both the local ladder
         (`_batch_fn`) and the sharded dispatcher run through it; the
         classify composition matches `pipeline.predict` numerically.
-        `plan` stands in for ``self.plan`` where the caller passes the
-        classifier tail as a traced argument.
+        `arg` stands in for the engine's own program argument (the
+        classifier tail, or the chain's weights) where the caller passes
+        it as a traced argument.
 
-        The stencil rung maps onto the classifier tail's two rungs: the
-        jnp floor ("ref") classifies through the staged oracle, every
-        fused stencil rung classifies through the fused tail."""
-        plan = self.plan if plan is None else plan
+        The filter task runs its chain at the rung as one `fused_chain`
+        launch.  The stencil rung maps onto the classifier tail's two
+        rungs: the jnp floor ("ref") classifies through the staged oracle,
+        every fused stencil rung classifies through the fused tail."""
+        arg = self._program_arg if arg is None else arg
+        if self.chain is not None:
+            stages = stencil.ir.respec(stencil.ir.spec_of(self.chain), arg)
+            gray = x.ndim == 3           # (B, H, W): one channel, no axis
+            out = stencil.fused_chain(x[..., None] if gray else x, stages,
+                                      mode=rung)
+            return {"image": out[..., 0] if gray else out}
+        plan = arg
         feats = pipeline.extract_features(
             x, self.config.replace(mode=rung), validate=False)
         if plan is not None:
@@ -249,18 +282,30 @@ class CvEngine:
         """One batch of the local ladder through the rung's compiled
         program: `jax.jit` of `_batch_body` at that rung, built on first
         use, which keeps one executable per (batch shape, dtype).  The
-        classifier tail goes in as an argument, so its arrays are not
-        compiled in.  Lowering errors fire while a shape's first call
-        traces, and a trace that raised caches nothing, so the next call
-        of that shape traces again.  ``stats["batch_traces"]`` counts
+        classifier tail or the chain's weights go in as an argument, so
+        their arrays are not compiled in.  Lowering errors fire while a
+        shape's first call traces, and a trace that raised caches nothing,
+        so the next call of that shape traces again.  ``stats["batch_traces"]`` counts
         the traces, not the calls."""
         fn = self._local_fns.get(rung)
         if fn is None:
-            def traced(xs, plan):
+            def traced(xs, arg):
                 self.stats["batch_traces"] += 1
-                return self._batch_body(xs, rung, plan)
+                return self._batch_body(xs, rung, arg)
             fn = self._local_fns[rung] = jax.jit(traced)
-        return fn(x, self.plan)
+        return fn(x, self._program_arg)
+
+    def _image_of(self, result: dict, row: int, hw) -> np.ndarray | None:
+        """A filter answer cut from its bucket back to the frame's own
+        output size: the edge padding reads as the chain's replicate
+        border, so the rest of the answer is what the frame alone gives."""
+        if "image" not in result:
+            return None
+        h, w = hw
+        for op, mode, *_ in stencil.resolve_chain(self.chain):
+            if mode == "map":
+                h, w = stencil.stage_out_hw(op, h, w)
+        return result["image"][row][:h, :w]
 
     def _run_batch(self, batch: np.ndarray, rung: str):
         """One canonical batch through the pipeline at one explicit rung."""
@@ -373,16 +418,18 @@ class CvEngine:
 
         Each phase runs under a `jax.profiler.TraceAnnotation`, so a
         profiler capture of a serving process shows them beside the
-        device ops: ``serve.submit`` (the whole call, keyword ``submit``:
-        its index) holds ``serve.admit`` (admission, bucketing, padding),
-        then per group batch ``serve.stack``, ``serve.h2d`` (copy to the
-        device), ``serve.dispatch`` (the rung's compiled program: traced
-        and compiled on a shape's first call, else looked up; enqueue),
+        device ops: ``serve.submit`` (the whole call; keywords ``submit``,
+        its index, and ``task``: extract, classify or filter) holds
+        ``serve.admit`` (admission, bucketing, padding), then per group
+        batch ``serve.stack``, ``serve.h2d`` (copy to the device),
+        ``serve.dispatch`` (the rung's compiled program: traced and
+        compiled on a shape's first call, else looked up; enqueue),
         ``serve.wait`` (the device computing), ``serve.d2h`` (the fetch)
         and ``serve.assemble`` (the Responses); the sharded path puts
         ``serve.fanout`` around its transfers and dispatch."""
         self._submits += 1
-        with TraceAnnotation("serve.submit", submit=self._submits):
+        with TraceAnnotation("serve.submit", submit=self._submits,
+                             task=self.task):
             reqs = [r if isinstance(r, Request) else Request(r) for r in workload]
             responses: list[Response | None] = [None] * len(reqs)
             groups: dict = {}
@@ -401,7 +448,8 @@ class CvEngine:
                     bucket = self._bucket_of(arr.shape)
                     canon = self._pad_to(arr, bucket)
                     gkey = (bucket or canon.shape[:2], canon.shape, str(canon.dtype))
-                    groups.setdefault(gkey, []).append((idx, canon, admitted))
+                    groups.setdefault(gkey, []).append(
+                        (idx, canon, admitted, arr.shape[:2]))
 
             # batched execution: sharded fan-out when a multi-device dispatcher
             # is attached, local ladder otherwise
@@ -434,7 +482,7 @@ class CvEngine:
                 responses[idx] = Response(
                     index=idx, ok=False, bucket=tuple(bucket),
                     error=f"floor_rung_failed: {type(e).__name__}: {e}",
-                    events=[ev for _, _, evs in part for ev in evs])
+                    events=[ev for m in part for ev in m[2]])
                 self.stats["errors"] += 1
             return
         dt = time.monotonic() - t0
@@ -451,6 +499,7 @@ class CvEngine:
                     valid=result["valid"][k] if "valid" in result else None,
                     pred=(int(result["pred"][k])
                           if "pred" in result else None),
+                    image=self._image_of(result, k, part[k][3]),
                     bucket=tuple(bucket), plan=plan, retries=retries,
                     degraded=degraded, deadline_missed=missed,
                     events=list(admit_events) + list(events),
@@ -514,6 +563,7 @@ class CvEngine:
                            if "valid" in sres.value else None),
                     pred=(int(sres.value["pred"][row])
                           if "pred" in sres.value else None),
+                    image=self._image_of(sres.value, row, part[k][3]),
                     bucket=tuple(bucket), plan=sres.plan,
                     retries=sres.redispatches, degraded=degraded,
                     deadline_missed=missed, shard=sres.shard,
@@ -529,6 +579,11 @@ class CvEngine:
         if self.model is None:
             raise ValueError("classify needs a trained model "
                              "(BowSvmModel or BowGbdtModel)")
+        return self.submit(imgs)
+
+    def filter(self, imgs) -> list[Response]:
+        if self.chain is None:
+            raise ValueError("filter needs a stencil chain (chain=)")
         return self.submit(imgs)
 
 

@@ -46,6 +46,14 @@ def _rgb_u8(n, lo=24, hi=32, seed=1):
                          dtype=np.uint8) for _ in range(n)]
 
 
+def _filter_chain(seed=0):
+    """filter2D 5x5 (OpenCV perf's draw: uniform in [-3, 10) over the
+    absolute value of its sum) -> 3x3 erode, the served filter task's chain."""
+    k = np.random.default_rng(seed).uniform(-3, 10, (5, 5))
+    return (stencil.filter_stage((k / abs(k.sum())).astype(np.float32)),
+            stencil.erode_stage(1))
+
+
 def _expected(eng, mode):
     """Recompute descriptors for every captured canonical batch at an
     explicit rung — the engine's output contract is defined on the padded
@@ -87,31 +95,36 @@ def test_engine_splits_batches_and_buckets():
     assert eng.stats["served"] == 8
 
 
-def test_submit_spans_each_phase_once_per_group(tmp_path):
+@pytest.mark.parametrize("task", ["extract", "filter"])
+def test_submit_spans_each_phase_once_per_group(tmp_path, task):
     """Under a profiler capture one submit leaves a `serve.submit` span
     holding `serve.admit` once and every per-batch phase once per group
-    batch (two buckets here), all nested inside it."""
+    batch (two buckets here), all nested inside it; the submit span names
+    the engine's task."""
     import collections
     from jax.profiler import ProfileData
     work = _rgb_u8(3) + _gray_f32(2, lo=40, hi=44, seed=2)
+    chain = _filter_chain() if task == "filter" else None
     eng = CvEngine(buckets=((32, 32), (48, 48)), max_batch=4, max_kp=8,
-                   ladder=("ref",))
-    eng.extract(work)                   # compiled outside the capture
+                   ladder=("ref",), chain=chain)
+    eng.submit(work)                    # compiled outside the capture
     with jax.profiler.trace(str(tmp_path)):
-        res = eng.extract(work)
+        res = eng.submit(work)
     assert all(r.ok for r in res)
     path = next(tmp_path.rglob("*.xplane.pb"))
-    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
-             for plane in ProfileData.from_file(str(path)).planes
-             if plane.name.startswith("/host:")
-             for line in plane.lines for e in line.events
-             if e.name.startswith("serve.")]
+    events = [e for plane in ProfileData.from_file(str(path)).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("serve.")]
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in events]
     per_batch = ("serve.stack", "serve.h2d", "serve.dispatch", "serve.wait",
                  "serve.d2h", "serve.assemble")
     assert collections.Counter(n for n, _, _ in spans) == {
         "serve.submit": 1, "serve.admit": 1, **{n: 2 for n in per_batch}}
     (_, lo, hi), = [s for s in spans if s[0] == "serve.submit"]
     assert all(lo <= s and e <= hi for _, s, e in spans)
+    (submit,) = [e for e in events if e.name == "serve.submit"]
+    assert dict(submit.stats)["task"] == task
 
 
 def test_repeat_submit_reuses_compiled_program(tmp_path):
@@ -200,6 +213,93 @@ def test_failed_trace_is_not_cached():
     assert eng.stats["batch_traces"] == 3
 
 
+@pytest.mark.parametrize("channels", [1, 3])
+def test_filter_task_serves_chain_ref(channels):
+    """Each u8 frame comes back as `ref.chain_ref` of the chain on that
+    frame alone, u8 and in the frame's shape: a frame padded up to its
+    bucket is cut back, and a float-accumulating stage may put a pixel at
+    a .5 tie one apart."""
+    gen = np.random.default_rng(11)
+    shapes = [(48, 128), (48, 128), (41, 100)]
+    work = [gen.integers(0, 256, hw + (channels,), dtype=np.uint8)
+            for hw in shapes]
+    chain = _filter_chain()
+    eng = CvEngine(None, chain=chain, buckets=((48, 128),), max_batch=4,
+                   ladder=("streaming",))
+    res = eng.filter(work)
+    for r, frame in zip(res, work):
+        assert r.ok and r.plan == "streaming" and not r.degraded
+        assert r.image.dtype == np.uint8 and r.image.shape == frame.shape
+        want = np.asarray(ref.chain_ref(jnp.asarray(frame), chain))
+        diff = np.abs(r.image.astype(np.int16) - want.astype(np.int16))
+        assert diff.max() <= 1
+        assert (diff > 0).mean() <= 1e-3
+    assert eng.signature == ("cv:filter:(('filter2d', (), None), "
+                             "('erode', (1,), None))")
+
+
+def test_filter_program_takes_the_weights_as_an_argument():
+    """Engines whose kernels differ only in their values trace once each
+    and lower to one program: the weights are an argument, not a
+    constant compiled in."""
+    work = _rgb_u8(2, lo=32, hi=32, seed=12)
+    engines = []
+    for seed in (0, 1):
+        eng = CvEngine(None, chain=_filter_chain(seed), buckets=((32, 32),),
+                       ladder=("streaming",))
+        for _ in range(2):
+            assert all(r.ok for r in eng.filter(work))
+        assert eng.stats["batch_traces"] == 1
+        engines.append(eng)
+    x = jnp.asarray(np.stack(work))
+    texts = [e._local_fns["streaming"].lower(x, e._program_arg).as_text()
+             for e in engines]
+    assert texts[0] == texts[1]
+
+
+def test_model_and_chain_together_are_refused():
+    kc, kw = jax.random.split(jax.random.PRNGKey(0))
+    model = pipeline.BowSvmModel(
+        centroids=jax.random.normal(kc, (16, 128)),
+        svm={"w": jax.random.normal(kw, (3, 16)), "b": jnp.zeros(3)},
+        n_classes=3)
+    with pytest.raises(ValueError):
+        CvEngine(model, chain=_filter_chain())
+    with pytest.raises(ValueError):     # a tap ladder ends in two bands
+        CvEngine(None, chain=(stencil.gaussian_stage(3),
+                              stencil.gaussian_stage(3, tap=-1)))
+    with pytest.raises(ValueError):
+        CvEngine(buckets=((32, 32),)).filter(_rgb_u8(1))
+
+
+def test_filter_sharded_matches_local():
+    """The sharded fan-out over four devices assembles the same images
+    as the local program."""
+    out = run_subprocess("""
+        import numpy as np
+        from repro.kernels import stencil
+        from repro.launch.mesh import make_cv_mesh
+        from repro.serve.cv_engine import CvEngine
+
+        gen = np.random.default_rng(13)
+        k = gen.uniform(-3, 10, (5, 5))
+        chain = (stencil.filter_stage((k / abs(k.sum())).astype(np.float32)),
+                 stencil.erode_stage(1))
+        work = [gen.integers(0, 256, (32, 64, 1), dtype=np.uint8)
+                for _ in range(8)]
+        kw = dict(chain=chain, buckets=((32, 64),), max_batch=8,
+                  ladder=("streaming",))
+        sharded = CvEngine(None, mesh=make_cv_mesh(), **kw).filter(work)
+        local = CvEngine(None, **kw).filter(work)
+        assert all(r.ok and r.shard is not None for r in sharded)
+        assert len({r.shard for r in sharded}) == 4
+        for s, l in zip(sharded, local):
+            np.testing.assert_array_equal(s.image, l.image)
+        print("SHARDED same")
+    """, devices=4)
+    assert "SHARDED same" in out
+
+
 def test_engine_rejects_malformed_frames():
     work = [np.zeros((8, 8, 2), np.uint8), np.zeros((8,), np.float32),
             np.zeros((16, 16), np.int32)] + _rgb_u8(1)
@@ -240,6 +340,26 @@ def test_lowering_fault_degrades_to_chain_ref_identical():
     for k, r in enumerate(res):
         np.testing.assert_array_equal(r.desc, desc[k])
         np.testing.assert_array_equal(r.valid, valid[k])
+
+
+@pytest.mark.parametrize("task", ["extract", "filter"])
+def test_floor_rung_failure_answers_every_frame(task):
+    """When the last rung fails every attempt, submit still returns one
+    Response per frame: not ok, with the floor's error."""
+    work = _rgb_u8(3, lo=40, hi=48, seed=14)
+    chain = _filter_chain() if task == "filter" else None
+    eng = CvEngine(None, chain=chain, buckets=((48, 48),), max_batch=8,
+                   max_kp=8,
+                   max_retries=0, ladder=("streaming",))
+    jax.clear_caches()
+    with faultinject.inject("lowering_error"):
+        res = eng.submit(work)
+    assert len(res) == len(work)
+    for k, r in enumerate(res):
+        assert r.index == k and not r.ok and r.bucket == (48, 48)
+        assert r.error.startswith("floor_rung_failed: InjectedFault")
+        assert isinstance(r.events, list)
+    assert eng.stats["errors"] == len(work) and eng.stats["served"] == 0
 
 
 def test_transient_fault_retries_same_rung():

@@ -184,6 +184,24 @@ def test_chain_working_set_fits_budget():
         assert ws.bytes(vc) <= vc.vmem_budget
 
 
+def test_u8_working_set_covers_what_mosaic_allocates():
+    """4K u8 filter2D 5x5 -> 3x3 erode, 16 planes: at 128-row bands Mosaic
+    allocates 16.78 MiB of scoped VMEM (16 MiB + 803.5 KiB; TPU v5e
+    compile), so the planner has to charge at least that there and pick
+    narrower bands: Mosaic holds each filter2D kernel row's slice of the
+    band, and the window's DMA block has its rows rounded up to the
+    sublane tile."""
+    k = np.random.default_rng(0).uniform(-3, 10, (5, 5))
+    chain = (stencil.filter_stage((k / abs(k.sum())).astype(np.float32)),
+             stencil.erode_stage(1))
+    tpu = VectorConfig(interpret=False)
+    ws = chain_working_set(chain, 3840, jnp.uint8, streaming=True)
+    assert ws.bytes(tpu.with_lmul(4)) >= 16 * 2**20 + 803.5 * 1024
+    vc = pick_chain_lmul(chain, 3840, jnp.uint8, base=tpu, streaming=True)
+    assert vc.lmul == 2 and ws.bytes(vc) <= vc.vmem_budget
+    assert plane_block(chain, 3840, 16, vc, jnp.uint8, streaming=True) == 1
+
+
 def test_plane_block_budget():
     vc = VectorConfig(lmul=4)
     p = plane_block(_chain3(), 512, 24, vc)
